@@ -119,7 +119,7 @@ def main(argv=None):
                                        interpret=True, bits="float32")
             rows.append((variant, length, B, M, K, N, win,
                          time.time() - t0))
-            print(f"{variant}/L{length} B{B} {M}x{K}x{N} -> bm,bn,bk={win} "
+            print(f"{variant}/L{length} B{B} {M}x{K}x{N} -> bm,bn={win} "
                   f"({rows[-1][-1]:.1f}s)", flush=True)
     nrows = len(rows) + tune_paged(autotune)
     print(f"# {nrows} keys -> {os.environ['REPRO_AUTOTUNE_CACHE']}")

@@ -501,9 +501,9 @@ def _chaos_rows(cfg, params, smoke):
         return serve_continuous(cfg, params, prompts, n_tokens, **knobs)[0]
 
     mon_stats = {}
-    # threshold calibration (ErrorModel moment sampling) is cold-start
+    # threshold calibration (one forward of each estimator) is cold-start
     # cost, not per-request serving overhead — build the watchdog once
-    monitor = watchdog_for_spec(DSCIM, probe_every=probe_every)
+    monitor = watchdog_for_spec(DSCIM, cfg, params, probe_every=probe_every)
 
     def monitored():
         # per-run counters (the watchdog object is reused across reps)

@@ -66,25 +66,6 @@ class ErrorModel:
             * jax.random.normal(key, exact_psum.shape, exact_psum.dtype)
         return exact_psum + noise
 
-    def relative_moment_bound(self, rows: int = 128) -> float:
-        """Expected *relative* per-output psum error of one ``rows``-row
-        accumulation window — the moment-derived scale the serving
-        accuracy watchdog (runtime/serving.py) turns into a logit-drift
-        threshold.
-
-        Numerator: |bias| + 1-sigma of the window error, ``|mu1|*rows +
-        sqrt(rows)*sig1`` (rows are independent by the remapping
-        property).  Denominator: the typical magnitude of an exact
-        ``rows``-row int8 psum under the calibration distribution,
-        ``sqrt(rows) * E[|x*w|]`` with x, w ~ U[-128, 128) (so E[x^2] =
-        E[w^2] ~ 128^2/3 and E[|xw|] = E|x|E|w| = 64^2).  A healthy
-        estimator's logit-level relative RMSE sits within a small multiple
-        of this bound (layer error partially averages out); a hard macro
-        fault is orders of magnitude above it."""
-        err = abs(self.mu1) * rows + np.sqrt(rows) * self.sig1
-        signal = np.sqrt(rows) * 64.0 * 64.0
-        return float(err / signal)
-
     def inject_paper(self, exact_psum, key, window: int = 128):
         """Paper-style injection (Sec. V: 'the DS-CIM error pattern was added
         to the MVM results'): one window-magnitude error per *output*,

@@ -130,7 +130,8 @@ def _mxu_opts(dim: int):
 
 def fused_tiles(shape, cfg, g: int, *, interpret: bool,
                 bits: str = "bfloat16"):
-    """(bm, bn, bk) winner for dscim_fused_mvm on (B, M, K, N) operands.
+    """(bm, bn) winner for dscim_fused_mvm on (B, M, K, N) operands (the
+    K step is always one whole quantization window).
 
     Decode serving shapes (M <= 16 — the skinny GEMV regime, batch riding
     the batch grid axis) get their own candidate set: the pad-free bm=M
@@ -148,18 +149,16 @@ def fused_tiles(shape, cfg, g: int, *, interpret: bool,
         bms = sorted({M, -(-M // 8) * 8, 16})
     else:
         bms = _mxu_opts(M)[:2]
-    cands = [(bm, bn, bk)
-             for bm in bms for bn in _mxu_opts(N)[:2]
-             for bk in (16, 32) if bk <= max(g, 16)]
+    cands = [(bm, bn) for bm in bms for bn in _mxu_opts(N)[:2]]
     # one shared operand set for all candidates (shape, not data, matters)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(0, 1, (B, M, K)), jnp.float32)
     w = jnp.asarray(rng.normal(0, 1, (K, N)), jnp.float32)
 
     def bench(cand):
-        bm, bn, bk = cand
+        bm, bn = cand
         return lambda: dscim_fused_mvm(
-            x, w, cfg, group_k=(g if g != K else None), bm=bm, bn=bn, bk=bk,
+            x, w, cfg, group_k=(g if g != K else None), bm=bm, bn=bn,
             bits=bits, interpret=interpret)
 
     return best(key, cands, bench)
@@ -180,12 +179,14 @@ def paged_attn_tiles(shape, page_size: int, *, interpret: bool):
     import jax.numpy as jnp
     import numpy as np
 
-    from .paged_attention import paged_attention_decode
+    from .paged_attention import chip_legal_ghs, paged_attention_decode
 
     B, KV, R, HD = shape
     key = f"paged_attn/B{B}/kv{KV}r{R}hd{HD}/ps{page_size}/" \
           f"{'cpu' if interpret else 'tpu'}"
-    ghs = sorted({g for g in (1, 2, 4, KV) if KV % g == 0})
+    # the chip refuses page blocks of other group sizes
+    ghs = sorted({g for g in (1, 2, 4, KV) if KV % g == 0}) if interpret \
+        else chip_legal_ghs(KV)
     qps = sorted({R, -(-R // 8) * 8})
     cands = [(gh, qp) for gh in ghs for qp in qps]
     MP = 4                               # representative decode page walk

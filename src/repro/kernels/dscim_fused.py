@@ -14,22 +14,27 @@ estimator inside the grid step:
     out[m,n] = Σ_u s_x[m,u] * s_w[u,n] * psum_u[m,n]
     psum_u   = scale*C_u - 128*Σx_u - 128*Σ(w_u+128)  (+ center-trunc terms)
 
-Every term is additive over K sub-tiles of a window, so each grid step
-contributes its partial counts *and* partial corrections, already multiplied
-by that window's dequant scales — no per-window psum ever exists in HBM; the
-only HBM traffic is the int8 operands, the tiny scale vectors and the final
-f32 output (same traffic class as a plain int8 matmul).
+Each K step is one whole quantization window (padded to 128 rows, the
+lane width), so it computes that window's counts *and* corrections and
+multiplies them by the window's dequant scales — no per-window psum ever
+exists in HBM; the only HBM traffic is the int8 operands, the tiny scale
+vectors and the final f32 output (same traffic class as a plain int8
+matmul).  Every block is legal for the TPU compiler (Mosaic): the scale
+operands come in whole along the window axis and the kernel selects the
+step's window, and the point tables are tiled per window row outside the
+kernel, so the body is plain 2-D compares and dots.
 
 Further wins over the staged path:
 
-* bit-expansion dot runs on **bf16** operands with f32 accumulation — {0,1}
+* bit-expansion dots run on **bf16** operands with f32 accumulation — {0,1}
   values are exact in bf16, counts ≤ K·pmax << 2^24 stay exact in the f32
   accumulator, VMEM for the bit tiles halves and the MXU runs at its
   bf16-input rate (``bits="float32"`` kept for A/B benchmarking);
 * leading batch dims map onto a **batch grid axis** (grid (B, M/bm, N/bn,
-  nw·spw)) instead of a reshape(-1, K) round-trip through HBM;
+  nw)) instead of a reshape(-1, K) round-trip through HBM;
 * blocked-points tables (disjointness theorem) shrink the contraction from
-  K·L to K·pmax exactly as in ``dscim_mvm_blocked``.
+  K·L to K·pmax exactly as in ``dscim_mvm_blocked``: one (bm, 128) x
+  (128, bn) {0,1} dot per point slot, pmax slots per window.
 
 In-kernel padding uses the never-fire sentinel x = w = -128 (x' = w' = 0):
 counts, Σ(w+128), Σa and Σb pad contributions are all zero by construction,
@@ -58,6 +63,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.macro import DSCIMConfig
@@ -69,41 +75,48 @@ from .ops import ON_TPU, default_bits, round_up as _round_up
 
 __all__ = ["dscim_fused_mvm", "dscim_fused_mvm_prepared",
            "dscim_fused_mvm_sharded", "quantize_activations_windowed",
-           "dscim_windowed_vmap_mvm"]
+           "dscim_windowed_vmap_mvm", "KERNEL_NAME"]
+
+# the Pallas kernel's name: it labels the kernel's custom call in compiled
+# programs (and its events in profiler traces)
+KERNEL_NAME = "dscim_fused_mvm"
+
+
+def window_rows(g: int) -> int:
+    """Rows of one padded quantization window: a grid step takes one whole
+    window, lane-aligned for the chip (g = 128, the serving window, pads
+    nothing)."""
+    return _round_up(g, 128)
 
 
 def _kernel(x_ref, w_ref, tu_ref, tv_ref, sx_ref, sw_ref, out_ref, *,
-            k: int, pmax: int, bk: int, spw: int, scale: float,
-            win_const: float, trunc_center: bool, bits: str):
-    """One grid step: partial counts + partial corrections of one window
-    sub-tile, dequantized by that window's scales, accumulated into out."""
+            k: int, pmax: int, scale: float, win_const: float,
+            trunc_center: bool, bits: str):
+    """One grid step: the counts and corrections of one whole quantization
+    window, dequantized by that window's scales, accumulated into out."""
     kk = pl.program_id(3)
-    sk = kk % spw                              # step index within the window
 
     @pl.when(kk == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...].astype(jnp.int32)           # (bm, bk) signed int8 values
-    w = w_ref[...].astype(jnp.int32)           # (bk, bn)
+    x = x_ref[...].astype(jnp.int32)           # (bm, gp) signed int8 values
+    w = w_ref[...].astype(jnp.int32)           # (gp, bn)
     a = (x + 128) >> k                         # shifted unsigned, [0, S)
     b = (w + 128) >> k
 
-    # row -> block wiring restarts at every window (the vmap-per-window
-    # semantics): row index *within the window* selects the point table row.
-    G = 4 ** k
-    rows = sk * bk + jax.lax.broadcasted_iota(jnp.int32, (bk,), 0)
-    blk = rows % G
-    lu = jnp.take(tu_ref[...], blk, axis=0)    # (bk, pmax)
-    lv = jnp.take(tv_ref[...], blk, axis=0)
-
-    bm, bn = x.shape[0], w.shape[1]
+    # one 2-D {0,1} dot per point slot: the row -> block wiring restarts at
+    # every window and a step is one whole window, so the point tables come
+    # in pre-tiled per row (tu (pmax, gp), tv (gp, pmax)); slot p of row r
+    # fires where its local coordinate is below the shifted operand
     bdt = jnp.dtype(bits)
-    abit = (lu[None, :, :] < a[:, :, None]).astype(bdt)   # (bm, bk, pmax)
-    wbit = (lv[:, :, None] < b[:, None, :]).astype(bdt)   # (bk, pmax, bn)
-    counts = jax.lax.dot_general(
-        abit.reshape(bm, bk * pmax), wbit.reshape(bk * pmax, bn),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    counts = jnp.zeros((x.shape[0], w.shape[1]), jnp.float32)
+    for p in range(pmax):
+        abit = (tu_ref[p:p + 1, :] < a).astype(bdt)        # (bm, gp)
+        wbit = (tv_ref[:, p:p + 1] < b).astype(bdt)        # (gp, bn)
+        counts = counts + jax.lax.dot_general(
+            abit, wbit, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     psum = scale * counts
     psum = psum - 128.0 * jnp.sum(x, axis=1, keepdims=True).astype(jnp.float32)
@@ -115,47 +128,61 @@ def _kernel(x_ref, w_ref, tu_ref, tv_ref, sx_ref, sw_ref, out_ref, *,
             jnp.sum(a, axis=1, keepdims=True)
             + jnp.sum(b, axis=0, keepdims=True)).astype(jnp.float32)
     if win_const:
-        # once per window: center-trunc constant + pad-sentinel cancellation
-        psum = psum + jnp.where(sk == 0, jnp.float32(win_const),
-                                jnp.float32(0.0))
-    out_ref[...] += psum * sx_ref[...] * sw_ref[...]
+        # center-trunc constant + pad-sentinel cancellation
+        psum = psum + jnp.float32(win_const)
+    # the scale blocks span every window; pick this window's column / row
+    sx = sx_ref[...]                                       # (bm, nw)
+    sw = sw_ref[...]                                       # (nw, bn)
+    sxk = jnp.sum(jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, sx.shape, 1) == kk, sx, 0.0),
+        axis=1, keepdims=True)
+    swk = jnp.sum(jnp.where(
+        jax.lax.broadcasted_iota(jnp.int32, sw.shape, 0) == kk, sw, 0.0),
+        axis=0, keepdims=True)
+    out_ref[...] += psum * sxk * swk
+
+
+def _tiled_tables(cfg: DSCIMConfig, gp: int):
+    """Point tables tiled to one padded window's rows: row r of a window
+    reads block r % G.  Pad rows (r >= g) never fire whatever they read —
+    their operands are the x = w = -128 sentinel."""
+    tu, tv, pmax = block_point_tables(cfg)
+    blk = np.arange(gp) % cfg.group
+    return tu[blk].T.copy(), tv[blk], pmax     # (pmax, gp), (gp, pmax)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "cfg", "g", "bm", "bn", "bk", "bits", "interpret"))
+    "cfg", "g", "bm", "bn", "bits", "interpret"))
 def _fused_call(xq, wq, sx, sw, cfg: DSCIMConfig, *, g: int, bm: int,
-                bn: int, bk: int, bits: str, interpret: bool):
+                bn: int, bits: str, interpret: bool):
     """xq (B, Mp, nw*gp) int8, wq (nw*gp, Np) int8, sx (B, Mp, nw) f32,
     sw (nw, Np) f32 -> (B, Mp, Np) f32."""
     B, Mp, KL = xq.shape
     Np = wq.shape[1]
-    gp = _round_up(g, bk)
-    spw = gp // bk
+    gp = window_rows(g)
     nw = KL // gp
-    tu_np, tv_np, pmax = block_point_tables(cfg)
+    tu_np, tv_np, pmax = _tiled_tables(cfg, gp)
     tu, tv = jnp.asarray(tu_np), jnp.asarray(tv_np)
-    G = cfg.group
     delta = (2 ** cfg.k - 1) / 2.0
     win_const = (g * delta * delta if cfg.trunc == "center" else 0.0) \
         - 128.0 * 128.0 * (gp - g)
     kernel = functools.partial(
-        _kernel, k=cfg.k, pmax=pmax, bk=bk, spw=spw, scale=cfg.scale,
-        win_const=win_const, trunc_center=(cfg.trunc == "center"), bits=bits)
+        _kernel, k=cfg.k, pmax=pmax, scale=cfg.scale, win_const=win_const,
+        trunc_center=(cfg.trunc == "center"), bits=bits)
     return pl.pallas_call(
         kernel,
-        grid=(B, Mp // bm, Np // bn, nw * spw),
+        grid=(B, Mp // bm, Np // bn, nw),
         in_specs=[
-            pl.BlockSpec((None, bm, bk), lambda b, i, j, kk: (b, i, kk)),
-            pl.BlockSpec((bk, bn), lambda b, i, j, kk: (kk, j)),
-            pl.BlockSpec((G, pmax), lambda b, i, j, kk: (0, 0)),
-            pl.BlockSpec((G, pmax), lambda b, i, j, kk: (0, 0)),
-            pl.BlockSpec((None, bm, 1),
-                         lambda b, i, j, kk, s=spw: (b, i, kk // s)),
-            pl.BlockSpec((1, bn), lambda b, i, j, kk, s=spw: (kk // s, j)),
+            pl.BlockSpec((None, bm, gp), lambda b, i, j, kk: (b, i, kk)),
+            pl.BlockSpec((gp, bn), lambda b, i, j, kk: (kk, j)),
+            pl.BlockSpec((pmax, gp), lambda b, i, j, kk: (0, 0)),
+            pl.BlockSpec((gp, pmax), lambda b, i, j, kk: (0, 0)),
+            pl.BlockSpec((None, bm, nw), lambda b, i, j, kk: (b, i, 0)),
+            pl.BlockSpec((nw, bn), lambda b, i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((None, bm, bn), lambda b, i, j, kk: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, Mp, Np), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name=KERNEL_NAME,
     )(xq, wq, tu, tv, sx, sw)
 
 
@@ -172,7 +199,7 @@ def quantize_activations_windowed(x, nw: int, g: int):
 
 def dscim_fused_mvm_prepared(x, qw: QuantizedLinearWeight, cfg: DSCIMConfig,
                              *, bm: int | None = None, bn: int | None = None,
-                             bk: int | None = None, bits: str | None = None,
+                             bits: str | None = None,
                              interpret: bool | None = None,
                              tune: bool = False):
     """Quantize-free fused DS-CIM linear: x (..., K) float + prepared weight
@@ -207,21 +234,18 @@ def dscim_fused_mvm_prepared(x, qw: QuantizedLinearWeight, cfg: DSCIMConfig,
 
     if tune:
         from . import autotune
-        bm, bn, bk = autotune.fused_tiles(
+        bm, bn = autotune.fused_tiles(
             (B, M, K, N), cfg, g, interpret=interpret, bits=bits)
-    bk = bk or min(16, g)
     if bm is None:
         # decode-shape (skinny-M GEMV) default: a pad-free bm=M tile — the
-        # M grid dim collapses to 1 and no dead rows are computed (the
-        # 8-row rounding would do 8x the M-work at M=1).  Interpret mode
-        # has no sublane-alignment constraint; on TPU the aligned default
-        # stays (Mosaic min sublane tiles) and the autotuner — whose
-        # candidate set includes bm=M — picks whatever actually wins.
+        # M grid dim collapses to 1 and no dead rows are computed.  Only
+        # interpret mode may take it: the chip needs sublane-aligned rows.
         bm = M if (M <= 16 and interpret) else min(128, _round_up(M, 8))
+    # a lane-aligned column tile, or the whole (8-padded) N when it is small
     bn = bn or min(128, _round_up(N, 8))
 
     xq = quantize_activations_windowed(x3, nw, g)       # (B,M,nw,1) scales
-    gp = _round_up(g, bk)
+    gp = window_rows(g)
     # never-fire sentinel padding (x' = w' = 0) along the window axis …
     x4 = jnp.pad(xq.q, ((0, 0), (0, 0), (0, 0), (0, gp - g)),
                  constant_values=-128)
@@ -239,21 +263,20 @@ def dscim_fused_mvm_prepared(x, qw: QuantizedLinearWeight, cfg: DSCIMConfig,
         w2 = jnp.pad(w2, ((0, 0), (0, padn)), constant_values=-128)
         sw = jnp.pad(sw, ((0, 0), (0, padn)))
     out = _fused_call(x2.astype(jnp.int8), w2.astype(jnp.int8), sx, sw, cfg,
-                      g=g, bm=bm, bn=bn, bk=bk, bits=bits,
-                      interpret=interpret)
+                      g=g, bm=bm, bn=bn, bits=bits, interpret=interpret)
     return out[:, :M, :N].reshape(*lead, N)
 
 
 def dscim_fused_mvm(x, w, cfg: DSCIMConfig, *, group_k: int | None = 128,
                     bm: int | None = None, bn: int | None = None,
-                    bk: int | None = None, bits: str | None = None,
+                    bits: str | None = None,
                     interpret: bool | None = None, tune: bool = False):
     """Fused DS-CIM linear from float weights: x (..., K), w (K, N) float
     -> (..., N) f32.  Exactly ``prepare_linear_weight`` + the prepared
     entry, so it is bit-identical to the serve path by construction."""
     qw = prepare_linear_weight(w, group_k)
-    return dscim_fused_mvm_prepared(x, qw, cfg, bm=bm, bn=bn, bk=bk,
-                                    bits=bits, interpret=interpret, tune=tune)
+    return dscim_fused_mvm_prepared(x, qw, cfg, bm=bm, bn=bn, bits=bits,
+                                    interpret=interpret, tune=tune)
 
 
 def dscim_fused_mvm_sharded(x, qw: QuantizedLinearWeight, cfg: DSCIMConfig,
@@ -283,7 +306,6 @@ def dscim_fused_mvm_sharded(x, qw: QuantizedLinearWeight, cfg: DSCIMConfig,
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel import shard_map
 
     nshard = mesh.shape[axis]
     t = axis if qw.n % nshard == 0 else None
@@ -312,8 +334,9 @@ def dscim_fused_mvm_sharded(x, qw: QuantizedLinearWeight, cfg: DSCIMConfig,
         qwl = QuantizedLinearWeight(ql, sl, qw.k_orig, qw.group_k)
         return dscim_fused_mvm_prepared(xl, qwl, cfg, **kw)
 
-    return shard_map(inner, mesh=mesh, in_specs=(xspec, qspec, sspec),
-                     out_specs=ospec)(x, qw.q, qw.scale)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(xspec, qspec, sspec), out_specs=ospec,
+                         check_vma=False)(x, qw.q, qw.scale)
 
 
 def dscim_windowed_vmap_mvm(x, w, cfg: DSCIMConfig, *,

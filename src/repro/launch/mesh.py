@@ -17,14 +17,11 @@ __all__ = ["make_mesh", "make_production_mesh", "make_parallel_ctx",
 
 
 def make_mesh(shape, axes):
-    """Version-portable jax.make_mesh: jax.sharding.AxisType only exists on
-    newer jax; Auto is the default axis type there anyway, so omit the
-    kwarg when unavailable.  Shared by launch and runtime mesh builders."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (GSPMD-propagated shardings, which
+    the sharding rules in launch/sharding.py are written for).  Shared by
+    launch and runtime mesh builders."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

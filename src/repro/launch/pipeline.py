@@ -67,12 +67,11 @@ def pipeline_forward(stage_params, x_mb, *, stage_fn, mesh,
             jnp.where(valid[:, None, None, None], outs, 0.0))
         return result
 
-    from repro.parallel import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params,
                                is_leaf=lambda x: False), P()),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )(stage_params, x_mb)
 
 

@@ -89,8 +89,10 @@ attention projections through the macro.
 Prepare-once weights (default, --no-prepare to A/B) and multi-chip meshes
 (--mesh, e.g. --mesh model=4) behave as in PR 2/3: prepared int8 planes
 shard N over 'model', the whole loop runs under the mesh, bit-identical
-to single-device serving.  The paged KV pool shards over the DP axes like
-the request batch (launch/sharding.py ``cache_partition``).
+to single-device serving on CPU devices (on TPU v5e chips the decode step
+is not yet bitwise; PERF.md has the measurements).  The paged KV pool
+shards over the DP axes like the request batch (launch/sharding.py
+``cache_partition``).
 
 The serve report compares greedy tokens + logit RMSE against the float
 path, which is the model-level reproduction of the paper's Table II
@@ -385,7 +387,10 @@ def _agreement(a: np.ndarray, b: np.ndarray, eos_id: int | None) -> float:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's reduced widths (default); "
+                         "--no-reduced serves its published widths")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--tokens", type=int, default=16)
@@ -494,6 +499,8 @@ def main(argv=None):
                          "serving decode shapes)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compilation_cache
+    use_compilation_cache()
     if args.chaos:
         from repro.runtime.serving import chaos_drill
         chaos_drill(args.arch, seed=args.chaos_seed)

@@ -191,11 +191,15 @@ def main(argv=None):
     import jax
 
     from repro.configs import get_arch
+    from repro.launch.compile_cache import use_compilation_cache
     from repro.models import get_model
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's reduced widths (default); "
+                         "--no-reduced serves its published widths")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8080)
     ap.add_argument("--slots", type=int, default=4)
@@ -213,6 +217,7 @@ def main(argv=None):
                     help="failover snapshot cadence in segments (0 = off)")
     args = ap.parse_args(argv)
 
+    use_compilation_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -77,9 +77,7 @@ def moe(params, x, *, top_k: int, capacity_factor: float = 1.25,
     block); the routed experts stay on the exact einsum path."""
     B, S, D = x.shape
     E = params["router"].shape[-1]
-    # jax.lax.axis_size is newer-jax; psum(1, axis) is the portable idiom
-    ep = (jax.lax.axis_size(ep_axis) if hasattr(jax.lax, "axis_size")
-          else jax.lax.psum(1, ep_axis)) if ep_axis else 1
+    ep = jax.lax.axis_size(ep_axis) if ep_axis else 1
     assert E % ep == 0, (E, ep)
 
     split_seq = bool(ep_axis) and ep > 1 and S % ep == 0

@@ -28,7 +28,7 @@ from repro.layers.attention import (attention, decode_attention,
 from repro.layers.mlp import init_mlp, mlp
 from repro.layers.moe import init_moe, moe, moe_local
 from repro.layers.norms import init_rmsnorm, layernorm, rmsnorm
-from repro.parallel import ParallelCtx, shard_map
+from repro.parallel import ParallelCtx
 
 __all__ = ["init_params", "forward", "prefill", "decode", "decode_multi",
            "cache_specs", "lm_loss"]
@@ -228,9 +228,9 @@ def _moe_apply(lp_moe, h, cfg: ArchConfig, par: ParallelCtx | None,
     if salt is not None:
         operands += (jnp.asarray(salt, jnp.int32),)
         in_specs += (P(),)
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=par.mesh, in_specs=in_specs,
-        out_specs=(P(dp, None, None), P()),
+        out_specs=(P(dp, None, None), P()), check_vma=False,
     )(*operands)
 
 
@@ -432,7 +432,7 @@ def decode(params, cfg: ArchConfig, batch, cache,
         body, x, (params["layers"], cache["k"], cache["v"],
                   jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = _norm(cfg, x, params["final_norm"])
-    logits = _head(params, cfg, x)[:, 0]
+    logits = _head(params, cfg, x, par)[:, 0]
     return logits, {"k": nk, "v": nv, "pos": _advance(pos, done)}
 
 
@@ -473,7 +473,7 @@ def _decode_paged(params, cfg: ArchConfig, batch, cache,
                   cache["k_tail"], cache["v_tail"],
                   jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = _norm(cfg, x, params["final_norm"])
-    logits = _head(params, cfg, x)[:, 0]
+    logits = _head(params, cfg, x, par)[:, 0]
     # dict(cache, ...) rebuild: bookkeeping planes that ride the cache but
     # are not rewritten per step (the integrity ``page_sum`` digests) must
     # pass through, not be dropped by an explicit-key reconstruction
@@ -555,7 +555,7 @@ def decode_multi(params, cfg: ArchConfig, batch, cache,
         new_cache = {"k": nk, "v": nv, "pos": pos + adv}
         win_kv = None
     x = _norm(cfg, x, params["final_norm"])
-    logits = _head(params, cfg, x)                        # (B,T,Vp)
+    logits = _head(params, cfg, x, par)                   # (B,T,Vp)
     return logits, new_cache, win_kv
 
 

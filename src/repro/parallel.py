@@ -10,19 +10,7 @@ import dataclasses
 
 import jax
 
-__all__ = ["ParallelCtx", "shard_map"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Version-portable shard_map: new jax exposes ``jax.shard_map`` with
-    ``check_vma``; 0.4.x has ``jax.experimental.shard_map`` with
-    ``check_rep``.  All repo call sites go through this wrapper."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
+__all__ = ["ParallelCtx"]
 
 
 @dataclasses.dataclass(frozen=True)

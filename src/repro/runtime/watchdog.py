@@ -14,8 +14,8 @@ The watchdog tracks a robust step-time baseline (EMA + MAD) and
 serving: every ``probe_every`` segments the fault-tolerant scheduler
 (runtime/serving.py) compares the serving path's logits against an
 exact-mode decode of the same (token, cache) inputs and trips a slot
-whose relative RMSE exceeds a threshold derived from the macro's
-``ErrorModel`` moments (core/error_model.py) — or whose logits go
+whose relative RMSE exceeds a threshold calibrated on the served model
+(runtime/serving.py ``watchdog_for_spec``) — or whose logits go
 NaN/Inf.  A tripped slot is quarantined and its request escalated down
 the degradation ladder (dscim2 -> dscim1 -> exact) instead of poisoning
 the rest of the batch.
@@ -108,8 +108,9 @@ class AccuracyWatchdog:
     """Sampled exact-vs-stochastic logit drift monitor for DS-CIM serving.
 
     ``rel_threshold``: maximum healthy per-slot relative logit RMSE
-    (``rmse(serving - exact) / rms(exact)``), normally derived from the
-    macro's measured error moments via ``from_error_model``; ``None``
+    (``rmse(serving - exact) / rms(exact)``), normally a margin over the
+    served model's measured healthy drift (runtime/serving.py
+    ``watchdog_for_spec``); ``None``
     disables drift probes (NaN/Inf detection stays on — the scheduler
     checks per-step logit finiteness every segment regardless).
     ``probe_every``: probe cadence in segments — the monitoring cost is
@@ -124,20 +125,6 @@ class AccuracyWatchdog:
         self.n_probes = 0
         self.n_trips = 0
         self.history: list = []    # (segment, per-slot rel rmse) tuples
-
-    @classmethod
-    def from_error_model(cls, em, margin: float = 3.0,
-                         probe_every: int = 8,
-                         rows: int = 128) -> "AccuracyWatchdog":
-        """Threshold = margin x the macro's moment-derived relative psum
-        error bound (core/error_model.py ``relative_moment_bound``).  The
-        margin absorbs layer-to-logit error propagation (partial
-        cancellation both ways); healthy logit drift sits ~2x the bound,
-        a hard macro fault ~an order of magnitude above it, so margin 3
-        separates cleanly (tests/test_serving_ft.py pins it
-        empirically)."""
-        return cls(margin * em.relative_moment_bound(rows),
-                   probe_every=probe_every)
 
     def should_probe(self, segment: int) -> bool:
         return self.rel_threshold is not None \

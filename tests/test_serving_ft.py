@@ -20,7 +20,8 @@ from repro.models import get_model
 from repro.runtime.failover import FailureInjector, flip_bits
 from repro.runtime.serving import (STATUS_DEADLINE, STATUS_OK,
                                    chaos_drill, exact_probe_spec,
-                                   next_ladder_spec, watchdog_for_spec)
+                                   healthy_logit_drift, next_ladder_spec,
+                                   watchdog_for_spec)
 from repro.runtime.watchdog import AccuracyWatchdog
 
 
@@ -248,18 +249,19 @@ def test_ladder_spec_algebra():
 
 
 def test_relative_moment_bound_scales():
-    from repro.core.dscim_layer import calibrated_config
-    from repro.core.error_model import ErrorModel
-    from repro.core.macro import DSCIMMacro
-    em1 = ErrorModel.from_macro(DSCIMMacro(calibrated_config("dscim1", 256,
-                                                             "paper")))
-    em2 = ErrorModel.from_macro(DSCIMMacro(calibrated_config("dscim2", 64,
-                                                             "paper")))
-    b1, b2 = em1.relative_moment_bound(), em2.relative_moment_bound()
-    assert 0 < b1 < b2                # dscim2 is the noisier point
-    wd = watchdog_for_spec("kernel:dscim2:64", probe_every=4)
-    assert wd.rel_threshold == pytest.approx(3.0 * b2)
+    """The drift threshold is a margin over the served model's measured
+    healthy drift: the noisier dscim2:64 point gets the looser threshold,
+    and an armed macro fault does not leak into the calibration."""
+    cfg, model, params = _setup("kernel:dscim1:256")
+    d1 = healthy_logit_drift("kernel:dscim1:256", cfg, params)
+    d2 = healthy_logit_drift("kernel:dscim2:64", cfg, params)
+    assert 0 < d1 < d2                # dscim2 is the noisier point
+    wd = watchdog_for_spec("kernel:dscim2:64", cfg, params, probe_every=4)
+    assert wd.rel_threshold == pytest.approx(3.0 * d2)
     assert wd.probe_every == 4
+    faulted = dataclasses.replace(cfg, dscim_fault="stuck:3:40.0")
+    assert healthy_logit_drift("kernel:dscim2:64", faulted, params) \
+        == pytest.approx(d2)
 
 
 # --------------------------------------------------------------------------
@@ -471,8 +473,8 @@ def test_nonfinite_quarantine_escalates():
 
 def test_macro_fault_drift_trips_and_escalates():
     """A persistent stuck-at macro fault drifts every live slot past the
-    moment-derived threshold; the healthy run never trips (the margin-3
-    calibration this pins: healthy ~2x the bound, faulted ~16x)."""
+    calibrated threshold; the healthy run never trips (the margin 3 this
+    pins: healthy probes within 1.5x the calibrated drift, faulted ~20x)."""
     spec = "kernel:dscim2:64"
     cfg, model, params = _setup(spec)
     prompts = np.random.default_rng(0).integers(0, cfg.vocab, (4, 8),
@@ -480,13 +482,13 @@ def test_macro_fault_drift_trips_and_escalates():
     budgets = np.asarray([6, 5, 6, 5], np.int32)
     knobs = dict(slots=2, seg_len=2, max_new=budgets, eos_id=-1,
                  kv="int8", page_size=4, log=lambda *a: None)
-    healthy = watchdog_for_spec(spec, probe_every=1)
+    healthy = watchdog_for_spec(spec, cfg, params, probe_every=1)
     outs_h, stats_h = serve_continuous(cfg, params, prompts, 6, **knobs,
                                        monitor=healthy)
     assert stats_h["probe_trips"] == 0 and not stats_h["quarantined"]
     rels = np.concatenate([h[np.isfinite(h)] for h in healthy.history])
     assert rels.max() < healthy.rel_threshold
-    faulted = watchdog_for_spec(spec, probe_every=1)
+    faulted = watchdog_for_spec(spec, cfg, params, probe_every=1)
     inj = FailureInjector(macro_fault_at=0, macro_fault="stuck:3:40.0")
     outs_f, stats_f = serve_continuous(cfg, params, prompts, 6, **knobs,
                                        monitor=faulted, injector=inj)

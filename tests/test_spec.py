@@ -382,17 +382,20 @@ def test_spec_deadline_counts_rejected_draft_positions():
 def test_spec_watchdog_probes_the_verifier():
     """aux['logits0'] under spec is the first window's verify logits at
     position 0 — verifier estimator, same (token, cache) inputs as the
-    exact probe.  A healthy dscim1 run must sit under the dscim1-derived
-    threshold; draft (dscim2) logits leaking into the probe would trip
-    it."""
-    from repro.runtime.serving import watchdog_for_spec
+    exact probe.  A healthy dscim1 run must sit under the threshold
+    calibrated for dscim1, with a drift nearer dscim1's than the draft
+    (dscim2) estimator's, which leaking draft logits would show."""
+    from repro.runtime.serving import healthy_logit_drift, watchdog_for_spec
     spec = "kernel:dscim1:256"
     cfg, params, _ = _setup(spec)
     prompts = np.random.default_rng(2).integers(0, cfg.vocab, (2, 8),
                                                 dtype=np.int32)
-    mon = watchdog_for_spec(spec, probe_every=1)
+    mon = watchdog_for_spec(spec, cfg, params, probe_every=1)
     _, st = serve_continuous(cfg, params, prompts, 6, slots=2, seg_len=2,
                              kv="int8", page_size=4, eos_id=-1,
                              monitor=mon, spec="dscim2:2")
     assert st["probes"] >= 1
     assert st["probe_trips"] == 0 and st["quarantined"] == []
+    d1 = mon.rel_threshold / 3.0
+    d2 = healthy_logit_drift("kernel:dscim2:64", cfg, params)
+    assert np.mean(np.concatenate(mon.history)) < (d1 + d2) / 2

@@ -158,7 +158,7 @@ def _chaos_monitor_overhead(smoke: bool) -> float:
     budgets = np.linspace(2, n_tokens, R).round().astype(np.int32)
     knobs = dict(slots=2, seg_len=4, max_new=budgets, eos_id=-1,
                  kv="int8", page_size=4)
-    monitor = watchdog_for_spec(spec, probe_every=8)
+    monitor = watchdog_for_spec(spec, cfg, params, probe_every=8)
 
     def plain():
         return serve_continuous(cfg, params, prompts, n_tokens, **knobs)[0]

@@ -19,7 +19,7 @@ Checks (no third-party deps — stdlib json only):
 * autotune_cache.json: a flat ``{key: [ints]}`` dict; keys must parse as
   a known kernel kind (``fused/`` / ``mvm/`` / ``paged_attn/``) ending in
   a cpu|tpu backend segment, and values must be positive-int tuples of
-  that kind's arity (fused (bm, bn, bk) = 3, mvm (bm, bn, bk, bl) = 4,
+  that kind's arity (fused (bm, bn) = 2, mvm (bm, bn, bk, bl) = 4,
   paged_attn (gh, qp) = 2).
 * serve/chaos_* rows (ISSUE 6): the fault-tolerance bench rows carry a
   typed derived contract — chaos_plain/chaos_monitored need a finite
@@ -94,7 +94,7 @@ CACHE_DEFAULT = os.path.join(REPO, "src", "repro", "kernels",
 
 _REV_RE = re.compile(r"^([0-9a-f]{7,40}|unknown)$")
 _TS_RE = re.compile(r"^\d{4}-\d{2}-\d{2}T\d{2}:\d{2}:\d{2}$")
-_ARITY = {"fused": 3, "mvm": 4, "paged_attn": 2}
+_ARITY = {"fused": 2, "mvm": 4, "paged_attn": 2}
 
 
 def _derived_fields(derived: str) -> dict:
