@@ -29,6 +29,7 @@ import asyncio
 import json
 import signal
 
+import jax
 import numpy as np
 
 from repro.runtime.router import Refused, Router
@@ -96,13 +97,14 @@ class HttpFrontend:
 
     async def _stream_generate(self, writer, body: bytes) -> None:
         try:
-            req = json.loads(body.decode() or "{}")
-            prompt = np.asarray(req["prompt"], np.int32)
-            handle = self.router.submit(
-                prompt, int(req.get("max_new", 16)),
-                deadline_s=req.get("deadline_s"),
-                deadline_steps=req.get("deadline_steps"),
-                priority=int(req.get("priority", 0)))
+            with jax.profiler.TraceAnnotation("http.submit"):
+                req = json.loads(body.decode() or "{}")
+                prompt = np.asarray(req["prompt"], np.int32)
+                handle = self.router.submit(
+                    prompt, int(req.get("max_new", 16)),
+                    deadline_s=req.get("deadline_s"),
+                    deadline_steps=req.get("deadline_steps"),
+                    priority=int(req.get("priority", 0)))
         except Refused as e:
             code, phrase = _REASON_HTTP[e.reason]
             hdr = {"Content-Type": "application/json",
@@ -130,12 +132,13 @@ class HttpFrontend:
 
         try:
             async for kind, val in handle.events():
-                if kind == "token":
-                    writer.write(chunk({"token": int(val)}))
-                elif kind == "restart":
-                    writer.write(chunk({"restart": True}))
-                else:
-                    writer.write(chunk({"status": val}))
+                with jax.profiler.TraceAnnotation("http.write"):
+                    if kind == "token":
+                        writer.write(chunk({"token": int(val)}))
+                    elif kind == "restart":
+                        writer.write(chunk({"restart": True}))
+                    else:
+                        writer.write(chunk({"status": val}))
                 await writer.drain()
             writer.write(b"0\r\n\r\n")
         except (ConnectionResetError, BrokenPipeError):
@@ -187,8 +190,6 @@ class HttpFrontend:
 
 def main(argv=None):
     import argparse
-
-    import jax
 
     from repro.configs import get_arch
     from repro.launch.compile_cache import use_compilation_cache

@@ -339,7 +339,13 @@ class Router:
             "admit_t": {},                   # rid -> wall admission anchor
             "admit_step": {},                # rid -> global_step at admission
             "segments": 0, "global_step": 0,
-            "live_steps": 0, "total_steps": 0,
+            # decode slot-steps: all, those that served a live request,
+            # those with no request in the slot, those held in chunked
+            # prefill (the rest are done-masked tails)
+            "slot_steps": 0, "live_slot_steps": 0,
+            "empty_slot_steps": 0, "prefill_slot_steps": 0,
+            # admissions and their summed submit -> admit seconds
+            "admitted": 0, "queue_wait_s": 0.0,
             "prefill_computed": 0, "prefill_total": 0,
             "counters": {"deadline_cancelled": 0, "cancelled": 0,
                          "quarantined": 0, "degraded": 0, "refused_queue": 0,
@@ -356,7 +362,6 @@ class Router:
         self._engine_task = None
         self._wake: asyncio.Event | None = None
         self._tok_s_ema = 0.0
-        self._t_start = time.perf_counter()
         self._resume_handles: dict = {}
         self._snapshot_blob = None
         if resume is not None:
@@ -468,7 +473,6 @@ class Router:
 
     def stats(self) -> dict:
         h = self._host
-        dt = time.perf_counter() - self._t_start
         useful = sum(len(o) for o in h["out"].values())
         done = [s for s in h["status"].values() if s is not None]
         return {
@@ -482,8 +486,10 @@ class Router:
             "segments": h["segments"],
             "replays": self._replays,
             "useful_tokens": useful,
-            "tok_s": useful / max(dt, 1e-9),
-            "occupancy": h["live_steps"] / max(h["total_steps"], 1),
+            "occupancy": h["live_slot_steps"] / max(h["slot_steps"], 1),
+            **{k: h[k] for k in ("slot_steps", "live_slot_steps",
+                                 "empty_slot_steps", "prefill_slot_steps",
+                                 "admitted", "queue_wait_s")},
             "pages": self._alloc.stats() if self._alloc is not None else None,
             "prefix": (dict(self._prefix.stats(),
                             prefill_positions_computed=h["prefill_computed"],
@@ -500,18 +506,25 @@ class Router:
     # ------------------------------------------------------------------
 
     def _take_snapshot(self) -> dict:
-        return {"state": jax.device_get(self._state),
-                "host": copy.deepcopy(self._host),
-                "alloc": self._alloc.snapshot()
-                if self._alloc is not None else None,
-                "prefix": self._prefix.snapshot()
-                if self._prefix is not None else None}
+        with jax.profiler.TraceAnnotation("router.snapshot"):
+            return {"state": jax.device_get(self._state),
+                    "host": copy.deepcopy(self._host),
+                    "alloc": self._alloc.snapshot()
+                    if self._alloc is not None else None,
+                    "prefix": self._prefix.snapshot()
+                    if self._prefix is not None else None}
 
     def _restore_blob(self, blob: dict) -> None:
         self._state = jax.device_put(blob["state"])
         self._host = copy.deepcopy(blob["host"])
-        self._host.setdefault("prefill_computed", 0)
-        self._host.setdefault("prefill_total", 0)
+        h = self._host
+        # blobs from before the slot-step counters were renamed and split
+        h.setdefault("slot_steps", h.pop("total_steps", 0))
+        h.setdefault("live_slot_steps", h.pop("live_steps", 0))
+        for k in ("empty_slot_steps", "prefill_slot_steps", "admitted",
+                  "prefill_computed", "prefill_total"):
+            h.setdefault(k, 0)
+        h.setdefault("queue_wait_s", 0.0)
         if blob["alloc"] is not None:
             self._alloc = PageAllocator.from_snapshot(blob["alloc"])
         if blob.get("prefix") is not None:
@@ -629,6 +642,8 @@ class Router:
             h["slot_rid"][b] = rid
             h["admit_t"][rid] = time.perf_counter()
             h["admit_step"][rid] = h["global_step"]
+            h["admitted"] += 1
+            h["queue_wait_s"] += h["admit_t"][rid] - req.submit_t
             if chunked:
                 # begin-admit: point the slot's page-table row at its
                 # grant and rewind its position past any shared prefix;
@@ -661,7 +676,9 @@ class Router:
                     self.params, self._state,
                     jnp.asarray(req.prompt[None]), jnp.int32(b), pages,
                     jnp.int32(req.max_new))
-                h["out"][rid].append(int(tok0))
+                with jax.profiler.TraceAnnotation("router.wait_first_token"):
+                    tok0 = int(tok0)
+                h["out"][rid].append(tok0)
                 h["slot_phase"][b] = "decode"
 
     def _feed_chunks(self) -> None:
@@ -685,13 +702,16 @@ class Router:
             if n_real < C:
                 part = np.pad(part, (0, C - n_real))
             emit = fed + n_real >= len(req.prompt)
-            self._state, tok0 = extend(
-                self.params, self._state, jnp.asarray(part[None]),
-                jnp.int32(b), jnp.int32(n_real), jnp.bool_(emit),
-                jnp.int32(req.max_new))
+            with jax.profiler.TraceAnnotation("router.extend"):
+                self._state, tok0 = extend(
+                    self.params, self._state, jnp.asarray(part[None]),
+                    jnp.int32(b), jnp.int32(n_real), jnp.bool_(emit),
+                    jnp.int32(req.max_new))
             h["slot_fed"][b] = fed + n_real
             if emit:
-                h["out"][rid].append(int(tok0))
+                with jax.profiler.TraceAnnotation("router.wait_first_token"):
+                    tok0 = int(tok0)
+                h["out"][rid].append(tok0)
                 h["slot_phase"][b] = "decode"
                 if self._prefix is not None and h["slot_pages"][b]:
                     # every fully-flushed prompt page is now immutable
@@ -761,6 +781,44 @@ class Router:
             return self.cfg
         return dataclasses.replace(self.cfg, dscim_fault=fault)
 
+    def _scrub(self) -> None:
+        """Integrity sweep at a segment boundary: repair weight planes
+        from the golden copy, check live KV pages; on any mismatch
+        restore the last verified snapshot (``IntegrityReplay``), else
+        take a new one."""
+        h = self._host
+        bad_w = self._integrity.check_weights(self.params)
+        if bad_w:
+            self.params = self._integrity.repair_weights(self.params,
+                                                         bad_w)
+            self.log(f"[router] integrity: weight plane(s) {bad_w} "
+                     "restored from golden copy")
+        coords = []
+        if self._alloc is not None:
+            pos_h = np.asarray(self._state["cache"]["pos"])
+            live_pages = np.zeros((self._alloc.n_pages,), bool)
+            for b in range(self.slots):
+                ids = h["slot_pages"][b]
+                if ids is not None:
+                    for p in ids[:int(pos_h[b]) // self.page_size]:
+                        live_pages[int(p)] = True
+            coords = self._integrity.check_pages(self._state["cache"],
+                                                 live_pages)
+            if coords:
+                self.log(f"[router] integrity: corrupted page(s) at "
+                         f"(layer, page) {coords}")
+        if bad_w or coords:
+            # slot-scoped repair lives in the scheduler
+            # (runtime/serving.py); the router takes the always-safe
+            # path — restore the last *verified* snapshot and replay.
+            # Repaired weights persist on self.params; transient
+            # flips fire once, so the replay runs clean.
+            self._integrity.note_replay()
+            self._snap = self._vsnap
+            raise IntegrityReplay(
+                f"weights {bad_w or 'clean'}, pages {coords or 'clean'}")
+        self._vsnap = self._take_snapshot()
+
     def _round(self) -> bool:
         """One engine round: ingest/cancel/harvest/deadline/admit, one
         chunk per prefilling slot, one decode segment if anything is
@@ -779,7 +837,8 @@ class Router:
             if req.cancelled and h["status"].get(rid) is None:
                 self._release(rid, STATUS_CANCELLED)
                 h["counters"]["cancelled"] += 1
-        done_h = np.asarray(self._state["done"])
+        with jax.profiler.TraceAnnotation("router.wait_done"):
+            done_h = np.asarray(self._state["done"])
         for b in range(self.slots):                    # harvest finished
             rid = h["slot_rid"][b]
             if rid >= 0 and h["slot_phase"][b] == "decode" and done_h[b]:
@@ -796,14 +855,16 @@ class Router:
                 self._release(rid, STATUS_REFUSED)     # retryable elsewhere
                 h["counters"]["refused_draining"] += 1
         else:
-            self._admit_waiting()
+            with jax.profiler.TraceAnnotation("router.admit"):
+                self._admit_waiting()
         self._feed_chunks()
 
         live_b = [b for b in range(self.slots)
                   if h["slot_rid"][b] >= 0 and h["slot_phase"][b] == "decode"]
         live0 = np.zeros((self.slots,), bool)
         if live_b:
-            done_h = np.asarray(self._state["done"])
+            with jax.profiler.TraceAnnotation("router.wait_done"):
+                done_h = np.asarray(self._state["done"])
             for b in live_b:
                 live0[b] = not done_h[b]
         if not live0.any():
@@ -814,7 +875,8 @@ class Router:
 
         lg_exact = None
         if self._probe is not None and self.monitor.should_probe(seg):
-            lg_exact = np.asarray(self._probe(self.params, self._state))
+            with jax.profiler.TraceAnnotation("router.probe"):
+                lg_exact = np.asarray(self._probe(self.params, self._state))
         corrupted: list = []
         if self.injector is not None and self._alloc is not None:
             cache2, hit = self.injector.corrupt_cache(
@@ -828,72 +890,51 @@ class Router:
             if whit:
                 self.params = p2
         if self._integrity is not None and self._integrity.due(seg):
-            bad_w = self._integrity.check_weights(self.params)
-            if bad_w:
-                self.params = self._integrity.repair_weights(self.params,
-                                                             bad_w)
-                self.log(f"[router] integrity: weight plane(s) {bad_w} "
-                         "restored from golden copy")
-            coords = []
-            if self._alloc is not None:
-                pos_h = np.asarray(self._state["cache"]["pos"])
-                live_pages = np.zeros((self._alloc.n_pages,), bool)
-                for b in range(self.slots):
-                    ids = h["slot_pages"][b]
-                    if ids is not None:
-                        for p in ids[:int(pos_h[b]) // self.page_size]:
-                            live_pages[int(p)] = True
-                coords = self._integrity.check_pages(self._state["cache"],
-                                                     live_pages)
-                if coords:
-                    self.log(f"[router] integrity: corrupted page(s) at "
-                             f"(layer, page) {coords}")
-            if bad_w or coords:
-                # slot-scoped repair lives in the scheduler
-                # (runtime/serving.py); the router takes the always-safe
-                # path — restore the last *verified* snapshot and replay.
-                # Repaired weights persist on self.params; transient
-                # flips fire once, so the replay runs clean.
-                self._integrity.note_replay()
-                self._snap = self._vsnap
-                raise IntegrityReplay(
-                    f"weights {bad_w or 'clean'}, pages {coords or 'clean'}")
-            self._vsnap = self._take_snapshot()
+            with jax.profiler.TraceAnnotation("router.integrity"):
+                self._scrub()
         cfg_now = self._cfg_now
         segment = self._segment if cfg_now is self.cfg else \
             make_segment_fn(cfg_now, self.par, self.seg_len,
                             eos_id=self.eos_id, sample=self.sample,
                             paged_attn=self.paged_attn, spec=self.spec)
-        self._state, toks, lives, aux = segment(self.params, self._state)
-        toks_h = np.asarray(toks)
-        lives_h = np.asarray(lives)
-        for s in range(toks_h.shape[0]):               # harvest tokens
-            for b in range(self.slots):
-                if lives_h[s, b] and h["slot_rid"][b] >= 0:
-                    h["out"][h["slot_rid"][b]].append(int(toks_h[s, b]))
-        if self.monitor is not None:
-            bad = np.asarray(aux["bad"]).any(axis=0)
-            trip = bad.copy()
-            if lg_exact is not None:
-                t2, _ = self.monitor.check(np.asarray(aux["logits0"]),
-                                           lg_exact, live0)
-                trip |= t2
-            for b in np.nonzero(trip)[0]:
-                rid = h["slot_rid"][int(b)]
-                if rid < 0:
-                    continue
-                self._free_slot(int(b))
-                self._state = dict(
-                    self._state,
-                    done=self._state["done"].at[int(b)].set(True))
-                h["out"][rid] = []          # discard poisoned tokens
-                h["restarted"][rid] = True
-                h["counters"]["quarantined"] += 1
-                self._ladder_reserve(rid)
-        h["live_steps"] += int(lives_h.sum())
-        h["total_steps"] += toks_h.shape[0] * self.slots
-        h["segments"] += 1
-        h["global_step"] += self.seg_len * (self._k_spec + 1)
+        n_empty = sum(rid < 0 for rid in h["slot_rid"])
+        n_prefill = h["slot_phase"].count("prefill")
+        with jax.profiler.TraceAnnotation("router.segment"):
+            self._state, toks, lives, aux = segment(self.params, self._state)
+        with jax.profiler.TraceAnnotation("router.wait_tokens"):
+            toks_h = np.asarray(toks)
+            lives_h = np.asarray(lives)
+        with jax.profiler.TraceAnnotation("router.harvest"):
+            for s in range(toks_h.shape[0]):
+                for b in range(self.slots):
+                    if lives_h[s, b] and h["slot_rid"][b] >= 0:
+                        h["out"][h["slot_rid"][b]].append(int(toks_h[s, b]))
+            if self.monitor is not None:
+                bad = np.asarray(aux["bad"]).any(axis=0)
+                trip = bad.copy()
+                if lg_exact is not None:
+                    t2, _ = self.monitor.check(np.asarray(aux["logits0"]),
+                                               lg_exact, live0)
+                    trip |= t2
+                for b in np.nonzero(trip)[0]:
+                    rid = h["slot_rid"][int(b)]
+                    if rid < 0:
+                        continue
+                    self._free_slot(int(b))
+                    self._state = dict(
+                        self._state,
+                        done=self._state["done"].at[int(b)].set(True))
+                    h["out"][rid] = []          # discard poisoned tokens
+                    h["restarted"][rid] = True
+                    h["counters"]["quarantined"] += 1
+                    self._ladder_reserve(rid)
+            n = toks_h.shape[0]
+            h["slot_steps"] += n * self.slots
+            h["live_slot_steps"] += int(lives_h.sum())
+            h["empty_slot_steps"] += n * n_empty
+            h["prefill_slot_steps"] += n * n_prefill
+            h["segments"] += 1
+            h["global_step"] += self.seg_len * (self._k_spec + 1)
         return True
 
     def _drain_snapshot(self) -> bool:
@@ -918,23 +959,24 @@ class Router:
         nothing re-streams; a ladder re-serve flips ``restarted`` and
         restreams from zero behind an explicit restart event."""
         h = self._host
-        for rid, req in self._requests.items():
-            if req.ended:
-                continue
-            out = h["out"].get(rid)
-            if out is None:
-                continue
-            if h["restarted"].get(rid) and not req.restart_sent:
-                req.queue.put_nowait(("restart", None))
-                req.restart_sent = True
-                req.sent = 0
-            while req.sent < len(out):
-                req.queue.put_nowait(("token", out[req.sent]))
-                req.sent += 1
-            status = h["status"].get(rid)
-            if status is not None and req.sent >= len(out):
-                req.queue.put_nowait(("end", status))
-                req.ended = True
+        with jax.profiler.TraceAnnotation("router.flush_streams"):
+            for rid, req in self._requests.items():
+                if req.ended:
+                    continue
+                out = h["out"].get(rid)
+                if out is None:
+                    continue
+                if h["restarted"].get(rid) and not req.restart_sent:
+                    req.queue.put_nowait(("restart", None))
+                    req.restart_sent = True
+                    req.sent = 0
+                while req.sent < len(out):
+                    req.queue.put_nowait(("token", out[req.sent]))
+                    req.sent += 1
+                status = h["status"].get(rid)
+                if status is not None and req.sent >= len(out):
+                    req.queue.put_nowait(("end", status))
+                    req.ended = True
 
     async def _engine(self) -> None:
         use_ft = self.injector is not None or self.snapshot_every > 0 \
@@ -947,7 +989,8 @@ class Router:
         t_last = time.perf_counter()
         while True:
             try:
-                busy = self._round()
+                with jax.profiler.TraceAnnotation("router.round"):
+                    busy = self._round()
             except _RECOVERABLE as e:
                 self._replays += 1
                 self.log(f"[router] {type(e).__name__}: {e}; replay "

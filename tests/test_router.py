@@ -4,9 +4,17 @@ invariant under chunk size (sequential-decode equivalence), typed
 admission refusals, mid-stream cancellation, submission-anchored wall
 deadlines, failover replay invisibility, quarantine -> degraded streams,
 snapshot-drain -> resume completion, and the zero-page-leak drain
-invariant — plus the loadtest helpers' trace shape."""
+invariant; the slot-step and queue-wait counters and the profiler spans of
+the round and the HTTP front end — plus the loadtest helpers' trace
+shape."""
 import asyncio
+import collections
 import dataclasses
+import glob
+import json
+import os
+import socket
+import time
 
 import jax
 import numpy as np
@@ -325,3 +333,183 @@ def test_loadtest_trace_shape():
     assert all(np.array_equal(a["prompt"], b["prompt"])
                and a["t"] == b["t"] and a["max_new"] == b["max_new"]
                for a, b in zip(trace, again))
+
+
+def test_slot_step_counters_partition_the_segments():
+    """Each decode slot-step of a segment is live, empty, held in chunked
+    prefill, or the done-masked tail of a request that ended mid-segment:
+    the counters add up, ``occupancy`` is live over all, every token but a
+    request's prefill-sampled first is a live step, and every admission is
+    counted."""
+    cfg, params = _setup()
+    rng = np.random.default_rng(8)
+    # bucketed (4, 8) and chunked (11: three chunks of 4; 6: two) prompts
+    prompts = [rng.integers(1, V, s).astype(np.int32)
+               for s in (4, 11, 8, 11, 6)]
+    budgets = [6, 3, 5, 2, 4]
+    slots, seg_len = 2, KN["seg_len"]
+
+    async def run():
+        r = _router(cfg, params, slots=slots)
+        await r.start()
+        res = await asyncio.gather(*[r.submit(p, b).result()
+                                     for p, b in zip(prompts, budgets)])
+        st = r.stats()
+        await _drained(r)
+        return res, st
+
+    res, st = asyncio.run(run())
+    assert [x.status for x in res] == ["ok"] * len(prompts)
+    total, live = st["slot_steps"], st["live_slot_steps"]
+    empty, pre = st["empty_slot_steps"], st["prefill_slot_steps"]
+    assert total == st["segments"] * seg_len * slots
+    assert st["occupancy"] == live / total
+    assert live == st["useful_tokens"] - len(prompts)
+    assert empty > 0 and pre > 0
+    assert live + empty + pre <= total
+    assert total - (live + empty + pre) \
+        <= st["segments"] * slots * (seg_len - 1)
+    assert st["admitted"] == len(prompts)
+    assert "tok_s" not in st
+
+
+def test_queue_wait_counts_time_behind_a_full_slot():
+    """A request submitted while every slot is busy is admitted only once
+    the slot frees: its submit -> admit time spans the first request's
+    whole decode, several engine rounds."""
+    cfg, params = _setup()
+    rng = np.random.default_rng(9)
+
+    async def run():
+        r = _router(cfg, params, slots=1)
+        await r.start()
+        h0 = r.submit(rng.integers(1, V, 4), 8)
+        h1 = r.submit(rng.integers(1, V, 4), 8)
+        t_submitted = time.perf_counter()
+        async for kind, _ in h0.events():
+            if kind == "token":
+                t_last = time.perf_counter()
+        assert (await h1.result()).status == "ok"
+        st = r.stats()
+        await _drained(r)
+        return st, t_last - t_submitted
+
+    st, behind = asyncio.run(run())
+    assert st["admitted"] == 2
+    # 7 decode steps of 2 per segment: at least 4 rounds behind the first
+    assert st["segments"] >= 8
+    assert st["queue_wait_s"] >= behind > 0
+
+
+def _record(tmp_path, coro):
+    """Run ``coro`` under the profiler (host spans, no Python tracer) and
+    return the host spans as name -> [(thread, start_ns, end_ns)]."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        asyncio.run(coro)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                s = int(ev.start_ns)
+                spans[ev.name].append((line.name, s,
+                                       s + int(ev.duration_ns)))
+    return spans
+
+
+def _inside(span, outer) -> bool:
+    th, s, e = span
+    return any(t == th and a <= s and e <= b for t, a, b in outer)
+
+
+def test_round_spans_nest_and_do_not_overlap(tmp_path):
+    """Under the profiler each round is one ``router.round`` span; the
+    round's phases lie inside one, rounds never overlap (no span holds an
+    await), and streams are flushed between rounds."""
+    cfg, params = _setup()
+    rng = np.random.default_rng(10)
+
+    async def run():
+        r = _router(cfg, params, slots=2)
+        await r.start()
+        hs = [r.submit(rng.integers(1, V, s), 5) for s in (4, 6, 11)]
+        res = await asyncio.gather(*[h.result() for h in hs])
+        assert [x.status for x in res] == ["ok"] * 3
+        await _drained(r)
+
+    spans = _record(tmp_path, run())
+    rounds = sorted(spans["router.round"], key=lambda x: x[1])
+    assert len(rounds) >= 3
+    for (t0, _, e0), (t1, s1, _) in zip(rounds, rounds[1:]):
+        assert t0 == t1 and e0 <= s1
+    for name in ("router.segment", "router.wait_tokens", "router.harvest",
+                 "router.wait_done", "router.admit", "router.extend",
+                 "router.wait_first_token"):
+        assert spans[name], name
+        assert all(_inside(sp, rounds) for sp in spans[name]), name
+    assert spans["router.flush_streams"]
+    assert not any(_inside(sp, rounds) for sp in spans["router.flush_streams"])
+
+
+def test_http_spans(tmp_path):
+    """The front end marks parsing and submitting a request
+    (``http.submit``) and writing each streamed event (``http.write``)."""
+    from repro.launch.server import HttpFrontend
+    cfg, params = _setup()
+    prompt = np.random.default_rng(11).integers(1, V, 6).tolist()
+
+    async def run():
+        with socket.socket() as sk:
+            sk.bind(("127.0.0.1", 0))
+            port = sk.getsockname()[1]
+        front = HttpFrontend(_router(cfg, params, slots=2), "127.0.0.1",
+                             port, log=lambda *a: None)
+        server = asyncio.create_task(front.serve())
+        while front._server is None:
+            await asyncio.sleep(0.01)
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        body = json.dumps({"prompt": prompt, "max_new": 3}).encode()
+        writer.write(b"POST /v1/generate HTTP/1.1\r\nContent-Length: "
+                     + str(len(body)).encode() + b"\r\n\r\n" + body)
+        await writer.drain()
+        reply = await reader.read()
+        writer.close()
+        front.request_drain()
+        await server
+        return reply
+
+    spans = _record(tmp_path, run())
+    assert len(spans["http.submit"]) == 1
+    # three tokens and the terminal status
+    assert len(spans["http.write"]) == 4
+    rounds = spans["router.round"]
+    assert not any(_inside(sp, rounds)
+                   for sp in spans["http.submit"] + spans["http.write"])
+
+
+def test_restore_fills_counters_missing_from_older_blobs():
+    """A snapshot blob written before the slot-step counters were renamed
+    and split restores with its counts under the new names."""
+    cfg, params = _setup()
+    r = _router(cfg, params, slots=2)
+    blob = r._take_snapshot()
+    host = blob["host"]
+    for k in ("slot_steps", "live_slot_steps", "empty_slot_steps",
+              "prefill_slot_steps", "admitted", "queue_wait_s"):
+        del host[k]
+    host["total_steps"], host["live_steps"] = 12, 9
+    r._restore_blob(blob)
+    st = r.stats()
+    assert (st["slot_steps"], st["live_slot_steps"]) == (12, 9)
+    assert st["occupancy"] == 0.75
+    assert st["empty_slot_steps"] == st["prefill_slot_steps"] == 0
+    assert st["admitted"] == 0 and st["queue_wait_s"] == 0.0
