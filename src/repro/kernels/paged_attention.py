@@ -36,6 +36,28 @@ This kernel is that loop, in one launch:
   them (their tail write and flush are gated host-side by ``done`` in
   layers/attention.py, which stays the jnp reference semantics).
 
+**Chunk entry** (``paged_attention_decode_chunk``, kernel name
+``paged_attention_decode_chunk``): T consecutive positions per slot —
+a chunked-prefill window, a prefix-cache admission, a speculative verify
+window — scored in one launch on the same (B, KV // gh, MP) grid, with
+the T positions' q rows (position-major, T*gh*qp of them) resident per
+cell.  The caller has already flushed every page the window fills into
+the pool, and hands in the window's pages at full precision as the
+slot's bf16 tail holds each while it is current.  For the query at
+position p, logical page j is read
+
+* as int8 from the pool if ``j < p // ps``;
+* from the window's bf16 page if ``j == p // ps``, tokens past p masked;
+* not at all past that page,
+
+which is the rule the per-position replay applies, on the same int8 and
+bf16 values, in the same page order with the same f32 contractions.  The
+int8 page and the bf16 page are stacked into one block and one 2-D dot
+scores both; rows run in tiles of positions, and a tile whose last
+position lies before page j skips it.  A slot's pages past the window's
+last one, and every page of a done slot, map to the block already
+resident (no DMA) and skip the body; done slots' outputs are zeros.
+
 The attended output (B, KV, n_rep, HD) comes out in f32; the q/k/v
 projections, RoPE, tail write and page flush stay in jnp around the call
 (they are O(B) scatter work, not the bandwidth term).  Numerics match the
@@ -61,10 +83,12 @@ Parity with the jnp reference is tested in interpret mode
 compile at serving widths is guarded by tests/test_tpu_compile.py.
 Under a mesh
 the call must sit inside shard_map (a Pallas call cannot be GSPMD-
-partitioned): ``paged_attention_decode_sharded`` shards the batch-carried
-operands (q, tails, page table, pos) over the DP axes and gathers the
-page pool whole per shard — under continuous batching any slot may
-reference any physical page, so the pool cannot shard with the slots.
+partitioned): ``paged_attention_decode_sharded`` (and
+``paged_attention_decode_chunk_sharded``) shards the batch-carried
+operands (q, tails or window pages, page table, pos) over the DP axes
+and gathers the page pool whole per shard — under continuous batching
+any slot may reference any physical page, so the pool cannot shard with
+the slots.
 """
 from __future__ import annotations
 
@@ -79,12 +103,17 @@ from jax.experimental.pallas import tpu as pltpu
 from .ops import ON_TPU
 
 __all__ = ["paged_attention_decode", "paged_attention_decode_sharded",
-           "use_paged_kernel", "KERNEL_NAME"]
+           "paged_attention_decode_chunk",
+           "paged_attention_decode_chunk_sharded", "use_paged_kernel",
+           "KERNEL_NAME", "CHUNK_KERNEL_NAME"]
 
 NEG_INF = -1e30
 # the Pallas kernel's name: it labels the kernel's custom call in compiled
 # programs (and its events in profiler traces)
 KERNEL_NAME = "paged_attention_decode"
+# the chunk entry's name holds KERNEL_NAME, so a match on that name counts
+# both entries' device time
+CHUNK_KERNEL_NAME = "paged_attention_decode_chunk"
 # full-f32 MXU contractions: the kernel matches the f32 jnp reference to
 # float-accumulation tolerance on the chip too, not to bf16 rounding
 _F32 = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
@@ -106,6 +135,16 @@ def use_paged_kernel(dscim_spec: str) -> bool:
         return False
     from repro.core.qweights import split_dscim_mode
     return split_dscim_mode(dscim_spec)[0] == "kernel"
+
+
+def _head_scales(s_ref, gh: int):
+    """The (1, gh, 1) f32 dequant scales of one page block's kv heads,
+    read from its (1, 1, 1, gh) SMEM scale block."""
+    hid = jax.lax.broadcasted_iota(jnp.int32, (1, gh, 1), 1)
+    sc = jnp.zeros((1, gh, 1), jnp.float32)
+    for h in range(gh):
+        sc = jnp.where(hid == h, s_ref[0, 0, 0, h], sc)
+    return sc
 
 
 def _kernel(table_ref, pos_ref, ks_ref, vs_ref, q_ref, kp_ref, vp_ref,
@@ -139,10 +178,7 @@ def _kernel(table_ref, pos_ref, ks_ref, vs_ref, q_ref, kp_ref, vp_ref,
 
     def dequant(p_ref, s_ref, t_ref, is_tail):
         """(ps, gh, HD) int8 page * per-head scale, or the bf16 tail."""
-        hid = jax.lax.broadcasted_iota(jnp.int32, (1, gh, 1), 1)
-        sc = jnp.zeros((1, gh, 1), jnp.float32)
-        for h in range(gh):
-            sc = jnp.where(hid == h, s_ref[0, 0, 0, h], sc)
+        sc = _head_scales(s_ref, gh)
         x = jnp.where(is_tail, t_ref[0].astype(jnp.float32),
                       p_ref[0].astype(jnp.float32) * sc)
         return x.reshape(ps * gh, x.shape[-1])
@@ -272,6 +308,15 @@ def paged_attention_decode(q, k_pages, v_pages, k_scale, v_scale,
                        interpret=interpret)
 
 
+def _slot_axes(mesh, dp_axes: tuple, batch: int):
+    """The mesh axes the slot dimension shards over: the DP axes when
+    they divide the batch, else None (replicated)."""
+    import math
+    if dp_axes and batch % math.prod(mesh.shape[a] for a in dp_axes) == 0:
+        return tuple(dp_axes)
+    return None
+
+
 def paged_attention_decode_sharded(q, k_pages, v_pages, k_scale, v_scale,
                                    k_tail, v_tail, page_table, pos, *,
                                    mesh, dp_axes: tuple = (), **kw):
@@ -286,16 +331,9 @@ def paged_attention_decode_sharded(q, k_pages, v_pages, k_scale, v_scale,
     lands batch-sharded.  Bit-identical to the single-device call: the
     per-slot page walk is placement-invariant.
     """
-    import math
-
     from jax.sharding import PartitionSpec as P
 
-
-    b = None
-    if dp_axes:
-        dp_size = math.prod(mesh.shape[a] for a in dp_axes)
-        if q.shape[0] % dp_size == 0:
-            b = tuple(dp_axes)
+    b = _slot_axes(mesh, dp_axes, q.shape[0])
     bspec4 = P(b, None, None, None)
     repl = lambda a: P(*([None] * a.ndim))  # noqa: E731
 
@@ -310,3 +348,197 @@ def paged_attention_decode_sharded(q, k_pages, v_pages, k_scale, v_scale,
         out_specs=bspec4, check_vma=False,
     )(q, k_pages, v_pages, k_scale, v_scale, k_tail, v_tail, page_table,
       pos.astype(jnp.int32))
+
+
+def _chunk_kernel(table_ref, pos_ref, lo_ref, hi_ref, ks_ref, vs_ref, q_ref,
+                  kp_ref, vp_ref, kc_ref, vc_ref, o_ref, acc_ref, m_ref,
+                  l_ref, *, ps: int, gh: int, qp: int, tq: int,
+                  scale: float):
+    """One grid step of the chunk entry: one logical page ``j`` of one
+    (slot, kv-head-group) cell, scored for all T window positions.
+
+    Blocks: q (1, 1, T*gh*qp, HD) f32 — position-major rows (t, head, r);
+    kp/vp (1, ps, gh, HD) int8 — pool page ``table[b, j]``; kc/vc (1, 1,
+    ps, gh, HD) — the window's page ``j`` at full precision; ks/vs
+    (1, 1, 1, gh) f32 in SMEM.  Scratch acc/m/l carry each row's online
+    softmax across the page axis.
+
+    The int8 page and the chunk page are stacked into one (2*ps*gh, HD)
+    block and every row scores both in one 2-D dot; the page rule masks
+    per row which half counts: the int8 half for a query whose own page
+    lies past ``j``, the chunk half (tokens up to the query) for a query
+    on page ``j``, neither past it.  Rows run in tiles of ``tq``
+    positions, and a tile whose last position lies before page ``j`` is
+    skipped.
+    """
+    b = pl.program_id(0)
+    j = pl.program_id(2)
+    nj = pl.num_programs(2)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    posb = pos_ref[b]
+    w = ps * gh                          # rows of one flattened page
+    nr = gh * qp                         # q rows per position
+    T = q_ref.shape[2] // nr
+
+    def stack(p_ref, s_ref, c_ref):
+        pool = p_ref[0].astype(jnp.float32) * _head_scales(s_ref, gh)
+        chunk = c_ref[0, 0].astype(jnp.float32)
+        return jnp.concatenate([pool.reshape(w, pool.shape[-1]),
+                                chunk.reshape(w, chunk.shape[-1])], axis=0)
+
+    # done slots (hi = -1) and pages past the window's last one do nothing
+    @pl.when(j <= hi_ref[b])
+    def _page():
+        kj = stack(kp_ref, ks_ref, kc_ref)                   # (2w, HD)
+        vj = stack(vp_ref, vs_ref, vc_ref)
+        for t0 in range(0, T, tq):
+            @pl.when(j * ps <= posb + t0 + tq - 1)
+            def _tile(t0=t0):
+                r0, nrow = t0 * nr, tq * nr
+                q = q_ref[0, 0, r0:r0 + nrow, :]
+                s = jax.lax.dot_general(                     # (nrow, 2w)
+                    q, kj, (((1,), (1,)), ((), ())), precision=_F32,
+                    preferred_element_type=jnp.float32) * scale
+                shape = s.shape
+                row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                p = posb + t0 + row // nr                    # query position
+                in_chunk = col >= w
+                cc = jnp.where(in_chunk, col - w, col)
+                past = (j + 1) * ps <= p                     # page j < p // ps
+                valid = (in_chunk & (j * ps + cc // gh <= p) & ~past) \
+                    | (~in_chunk & past)
+                valid = valid & (cc % gh == (row // qp) % gh)
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_ref[r0:r0 + nrow, :]
+                m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+                pr = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[r0:r0 + nrow, :] = (l_ref[r0:r0 + nrow, :] * alpha
+                                          + pr.sum(-1, keepdims=True))
+                acc_ref[r0:r0 + nrow, :] = (
+                    acc_ref[r0:r0 + nrow, :] * alpha + jax.lax.dot_general(
+                        pr, vj, (((1,), (0,)), ((), ())), precision=_F32,
+                        preferred_element_type=jnp.float32))
+                m_ref[r0:r0 + nrow, :] = m_new
+
+    @pl.when(j == nj - 1)
+    def _finalize():
+        o_ref[0, 0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _tile_positions(T: int, nr: int, rows: int = 256) -> int:
+    """Window positions per row tile of the chunk entry: the largest
+    divisor of T whose ``nr``-row positions fit ``rows`` rows in whole
+    8-row groups (the chip's sublane tiling), else all T."""
+    for tq in range(T, 0, -1):
+        if T % tq == 0 and tq * nr <= rows and (tq == T or tq * nr % 8 == 0):
+            return tq
+    return T
+
+
+@functools.partial(jax.jit, static_argnames=("gh", "interpret"))
+def _chunk_call(q, k_pages, v_pages, k_scale, v_scale, k_chunk, v_chunk,
+                page_table, pos, done, *, gh: int, interpret: bool):
+    B, T, KV, R, HD = q.shape
+    ps = k_pages.shape[1]
+    MP = page_table.shape[1]
+    NC = k_chunk.shape[1]
+    G, nr = KV // gh, gh * R
+    q = q.reshape(B, T, G, nr, HD).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, G, T * nr, HD)
+    lo = pos // ps
+    hi = jnp.where(done, -1, (pos + T - 1) // ps)
+    kernel = functools.partial(_chunk_kernel, ps=ps, gh=gh, qp=R,
+                               tq=_tile_positions(T, nr), scale=HD ** -0.5)
+
+    # a slot's pages past its last one (and every page of a done slot)
+    # map to the block already resident, so the pipeline issues no DMA
+    def page_of(b, j, h):
+        return jnp.maximum(jnp.minimum(j, h[b]), 0)
+
+    page = pl.BlockSpec(
+        (1, ps, gh, HD),
+        lambda b, g, j, t, p, lo_, h: (t[b, page_of(b, j, h)], 0, g, 0))
+    chunk = pl.BlockSpec(
+        (1, 1, ps, gh, HD),
+        lambda b, g, j, t, p, lo_, h: (
+            b, jnp.clip(page_of(b, j, h) - lo_[b], 0, NC - 1), 0, g, 0))
+    scales = pl.BlockSpec(
+        (1, 1, 1, gh),
+        lambda b, g, j, t, p, lo_, h: (t[b, page_of(b, j, h)], g, 0, 0),
+        memory_space=pltpu.SMEM)
+    rows = pl.BlockSpec((1, 1, T * nr, HD),
+                        lambda b, g, j, t, p, lo_, h: (b, g, 0, 0))
+    P = k_scale.shape[0]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, G, MP),
+        in_specs=[scales, scales, rows, page, page, chunk, chunk],
+        out_specs=rows,
+        scratch_shapes=[pltpu.VMEM((T * nr, HD), jnp.float32),
+                        pltpu.VMEM((T * nr, 1), jnp.float32),
+                        pltpu.VMEM((T * nr, 1), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, G, T * nr, HD), jnp.float32),
+        interpret=interpret, name=CHUNK_KERNEL_NAME,
+    )(page_table, pos, lo, hi, k_scale.reshape(P, G, 1, gh),
+      v_scale.reshape(P, G, 1, gh), q, k_pages, v_pages, k_chunk, v_chunk)
+    return out.reshape(B, G, T, nr, HD).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, T, KV, R, HD)
+
+
+def paged_attention_decode_chunk(q, k_pages, v_pages, k_scale, v_scale,
+                                 k_chunk, v_chunk, page_table, pos, done, *,
+                                 interpret: bool | None = None):
+    """Paged attention for a window of T positions per slot in one launch
+    (see module docstring).
+
+    q (B, T, KV, n_rep, HD) f32 — post-RoPE queries of positions
+    ``pos .. pos+T-1``, kv-major heads; k/v_pages (P, ps, KV, HD) int8 and
+    k/v_scale (P, KV) f32 — the pool with every page the window fills
+    already flushed into it; k/v_chunk (B, NC, ps, KV, HD) bf16 — the
+    window's logical pages ``pos // ps + c`` at full precision, as the
+    slot's tail holds each while it is current; page_table (B, MP) int32;
+    pos (B,) int32; done (B,) bool.  Returns (B, T, KV, n_rep, HD) f32:
+    row t is what ``paged_attention_decode`` gives at position pos+t with
+    the tail holding that position's page; done rows are zeros.
+    """
+    interpret = (not ON_TPU) if interpret is None else interpret
+    KV = q.shape[2]
+    return _chunk_call(q, k_pages, v_pages, k_scale, v_scale, k_chunk,
+                       v_chunk, page_table, pos.astype(jnp.int32),
+                       done.astype(bool), gh=chip_legal_ghs(KV)[0],
+                       interpret=interpret)
+
+
+def paged_attention_decode_chunk_sharded(q, k_pages, v_pages, k_scale,
+                                         v_scale, k_chunk, v_chunk,
+                                         page_table, pos, done, *, mesh,
+                                         dp_axes: tuple = ()):
+    """Mesh placement of the chunk entry, as
+    ``paged_attention_decode_sharded`` places the decode entry: the
+    slot-carried operands (q, chunk pages, page table, pos, done) shard
+    over the DP axes, the pool and scales replicate into each shard."""
+    from jax.sharding import PartitionSpec as P
+
+    b = _slot_axes(mesh, dp_axes, q.shape[0])
+    slots = lambda a: P(b, *([None] * (a.ndim - 1)))  # noqa: E731
+    repl = lambda a: P(*([None] * a.ndim))  # noqa: E731
+    args = (q, k_pages, v_pages, k_scale, v_scale, k_chunk, v_chunk,
+            page_table, pos.astype(jnp.int32), done.astype(bool))
+    return jax.shard_map(
+        paged_attention_decode_chunk, mesh=mesh,
+        in_specs=(slots(q), repl(k_pages), repl(v_pages), repl(k_scale),
+                  repl(v_scale), slots(k_chunk), slots(v_chunk),
+                  slots(page_table), P(b), P(b)),
+        out_specs=slots(q), check_vma=False,
+    )(*args)

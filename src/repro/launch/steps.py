@@ -809,7 +809,15 @@ def make_extend_fn(cfg: ArchConfig, par: ParallelCtx | None = None,
     Position semantics: the slot's KV at positions ``pos .. pos+n_real-1``
     after the call is bitwise what ``n_real`` successive single-token
     teacher-forced ``decode`` steps would have written (the decode_multi
-    exact-replay guarantee) — chunked prefill is *sequential-decode*
+    exact-replay guarantee).  On the kernel read path with
+    ``chunk_len > 1`` that holds by construction, not by a per-position
+    scan: each layer flushes the pages the chunk fills up front, then one
+    launch of the paged kernel's chunk entry reads, for each position,
+    the pool pages before its own and the bf16 copy of its own page
+    (layers/attention.py ``decode_attention_paged_multi``); the attention
+    output matches the sequential replay to f32 accumulation order.
+    ``chunk_len = 1`` and the jnp read path replay it per position.
+    Chunked prefill is *sequential-decode*
     equivalent, not bitwise-equal to the batched full-prompt prefill
     (XLA reduces the S-position attention in a different order), which is
     why the router's bucketed one-shot path exists for common lengths.

@@ -473,46 +473,23 @@ def decode_attention_paged(params, x, view, cfg, linear=None, salt=None,
     return out, (k_pages, v_pages, k_scale, v_scale, k_tail, v_tail)
 
 
-def decode_attention_paged_multi(params, x, view, cfg, linear=None, salt=None,
-                                 done=None, par=None, use_kernel=None):
-    """Speculative-verify decode against one layer of the int8 paged cache:
-    score T consecutive tokens per row in one call.
-
-    Projections batch over the window; the tail-write / page-walk / flush
-    sequence runs per position inside a ``lax.scan``, replaying
-    ``decode_attention_paged`` exactly (write tail at ``pt % ps``, read with
-    the frozen-``pt`` ragged mask — which is how the kernel's masking covers
-    in-flight draft positions — then quantize-once flush when ``pt`` fills a
-    page), so position t is bitwise-identical to t successive single-token
-    decodes.  ``done`` rows freeze ``pt`` and suppress writes + flushes,
-    exactly like the single-token path.
-
-    Also returns the window's K/V projections in tail dtype — the
-    speculative rollback (core/kvcache.spec_rollback) needs them to rebuild
-    the committed tail when a rejected window crossed a page boundary.
-
-    Returns (out (B, T, D),
-             (k_pages, v_pages, k_scale, v_scale, k_tail, v_tail),
-             (win_k, win_v))  with win_k/win_v (B, T, KV, HD) tail-dtype.
+def _paged_multi_scan(qf, view, win_k, win_v, done, par, use_kernel):
+    """Multi-position paged attention as a per-position ``lax.scan`` that
+    replays ``decode_attention_paged`` exactly: write the tail at
+    ``pt % ps``, read with the ragged mask at ``pt`` (through the kernel
+    or the jnp read path), then the quantize-once flush when ``pt`` fills
+    a page.  ``done`` rows freeze ``pt`` and suppress writes + flushes.
+    qf (B, T, KV, n_rep, HD) f32; returns (out (B, T, KV, n_rep, HD) f32,
+    (k_pages, v_pages, k_scale, v_scale, k_tail, v_tail)).
     """
     from repro.core.kvcache import quantize_page
-    from repro.kernels.paged_attention import use_paged_kernel
 
-    B, T, _ = x.shape
+    B, T = qf.shape[:2]
     pos = view["pos"]
     page_table = view["page_table"]
-    n_pages, ps, KV, HD = view["k_pages"].shape
+    n_pages, ps = view["k_pages"].shape[:2]
     step = jnp.ones((B,), jnp.int32) if done is None \
         else jnp.where(done, 0, 1).astype(jnp.int32)
-    offs = jnp.arange(T, dtype=jnp.int32)
-    positions = pos[:, None].astype(jnp.int32) + step[:, None] * offs[None, :]
-    q, k, v = _qkv(params, x, cfg.n_heads, cfg.n_kv, cfg.head_dim,
-                   positions, cfg.rope_theta, cfg.qk_norm, linear, salt)
-    n_rep = q.shape[2] // KV
-    if use_kernel is None:
-        use_kernel = use_paged_kernel(getattr(cfg, "dscim", "off"))
-    win_k = k.astype(view["k_tail"].dtype)                # (B,T,KV,HD)
-    win_v = v.astype(view["v_tail"].dtype)
 
     def upd(t, vv, o):
         return jax.lax.dynamic_update_slice_in_dim(t, vv[None], o, 0)
@@ -530,11 +507,10 @@ def decode_attention_paged_multi(params, x, view, cfg, linear=None, salt=None,
         viewt = {"k_pages": k_pages, "v_pages": v_pages,
                  "k_scale": k_scale, "v_scale": v_scale,
                  "page_table": page_table, "pos": pt}
-        qf = qt.astype(jnp.float32).reshape(B, KV, n_rep, HD)
         if use_kernel:
-            ot = _paged_read_kernel(qf, viewt, nkt, nvt, par)
+            ot = _paged_read_kernel(qt, viewt, nkt, nvt, par)
         else:
-            ot = _paged_read_jnp(qf, viewt, nkt, nvt)
+            ot = _paged_read_jnp(qt, viewt, nkt, nvt)
         full = (pt + 1) % ps == 0
         if done is not None:
             full = full & ~done
@@ -553,9 +529,146 @@ def decode_attention_paged_multi(params, x, view, cfg, linear=None, salt=None,
               view["v_scale"], view["k_tail"], view["v_tail"])
     planes, outs = jax.lax.scan(
         pstep, carry0,
-        (jnp.moveaxis(q, 1, 0), jnp.moveaxis(win_k, 1, 0),
-         jnp.moveaxis(win_v, 1, 0), offs))
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, T, -1).astype(x.dtype)
+        (jnp.moveaxis(qf, 1, 0), jnp.moveaxis(win_k, 1, 0),
+         jnp.moveaxis(win_v, 1, 0), jnp.arange(T, dtype=jnp.int32)))
+    return jnp.moveaxis(outs, 0, 1), planes
+
+
+def _chunk_pages(win, tail0, first):
+    """The bf16 pages a T-position window covers, each as the slot's tail
+    holds it while that page is current, (B, nc, ps, KV, HD); ``first``
+    (B, nc) is the window index of each page's first token.  A row takes
+    the window's K/V of its position, the pre-window tail before the
+    window, and past the window's end what the sequential tail holds
+    there: the window's last write at that offset, else the pre-window
+    tail."""
+    B, T = win.shape[:2]
+    nc, ps = first.shape[1], tail0.shape[1]
+    t = first[..., None] + jnp.arange(ps, dtype=jnp.int32)    # (B, nc, ps)
+    t = jnp.where(t >= T, t - ps * ((t - T + ps) // ps), t).reshape(B, -1)
+    g = jnp.take_along_axis(win, jnp.clip(t, 0, T - 1)[..., None, None],
+                            axis=1)
+    g = jnp.where((t >= 0)[..., None, None], g, jnp.tile(tail0, (1, nc, 1, 1)))
+    return g.reshape(B, nc, ps, *win.shape[2:])
+
+
+def _paged_multi_kernel(qf, view, win_k, win_v, done, par):
+    """Multi-position paged attention on the kernel read path, in one
+    launch: flush every page the window fills, then score all T positions
+    against the flushed pool and the window's bf16 pages
+    (kernels/paged_attention.py ``paged_attention_decode_chunk``).
+
+    The pool, scales and tails it leaves are bitwise what the per-position
+    scan of ``decode_attention_paged_multi`` leaves: each flushed page is
+    ``quantize_page`` of the bf16 page the scan's tail holds when that
+    page fills, written by the same ``mode="drop"`` scatter, and the tail
+    is the window's last page as the scan leaves it.  Each position reads
+    pages before its own from the pool (already flushed when the scan
+    reads them) and its own page from the bf16 copy, as the scan does.
+    Done rows write nothing and their outputs are zeros.
+    qf (B, T, KV, n_rep, HD) f32; returns (out (B, T, KV, n_rep, HD) f32,
+    (k_pages, v_pages, k_scale, v_scale, k_tail, v_tail)).
+    """
+    from repro.core.kvcache import quantize_page
+    from repro.kernels.paged_attention import (
+        paged_attention_decode_chunk, paged_attention_decode_chunk_sharded)
+
+    B, T, KV, HD = win_k.shape
+    pos = view["pos"]
+    page_table = view["page_table"]
+    n_pages, ps = view["k_pages"].shape[:2]
+    nc = (T + 2 * ps - 2) // ps              # most pages T positions span
+    done = jnp.zeros((B,), bool) if done is None else done
+    lpage = (pos // ps)[:, None] + jnp.arange(nc, dtype=jnp.int32)
+    k_chunk = _chunk_pages(win_k, view["k_tail"], lpage * ps - pos[:, None])
+    v_chunk = _chunk_pages(win_v, view["v_tail"], lpage * ps - pos[:, None])
+
+    # flush: a page the window fills is quantized once and scattered to
+    # its physical page; others (and done rows) go to the dropped sentinel
+    full = (lpage * ps + ps - 1 <= (pos + T - 1)[:, None]) & ~done[:, None]
+    phys = jnp.take_along_axis(page_table, lpage, 1)
+    idx = jnp.where(full, phys, n_pages).reshape(-1)
+    planes = []
+    for name, chunk in (("k", k_chunk), ("v", v_chunk)):
+        qv, sv = quantize_page(chunk)
+        planes.append(view[f"{name}_pages"].at[idx].set(
+            qv.reshape(-1, ps, KV, HD), mode="drop"))
+        planes.append(view[f"{name}_scale"].at[idx].set(
+            sv.reshape(-1, KV), mode="drop"))
+    k_pages, k_scale, v_pages, v_scale = planes
+
+    last = ((pos + T - 1) // ps - pos // ps)[:, None, None, None, None]
+    keep = done[:, None, None, None]
+    k_tail = jnp.where(keep, view["k_tail"],
+                       jnp.take_along_axis(k_chunk, last, 1)[:, 0])
+    v_tail = jnp.where(keep, view["v_tail"],
+                       jnp.take_along_axis(v_chunk, last, 1)[:, 0])
+
+    args = (qf, k_pages, v_pages, k_scale, v_scale, k_chunk, v_chunk,
+            page_table, pos, done)
+    if par is not None:
+        out = paged_attention_decode_chunk_sharded(
+            *args, mesh=par.mesh, dp_axes=par.dp_axes)
+    else:
+        out = paged_attention_decode_chunk(*args)
+    return out, (k_pages, v_pages, k_scale, v_scale, k_tail, v_tail)
+
+
+def decode_attention_paged_multi(params, x, view, cfg, linear=None, salt=None,
+                                 done=None, par=None, use_kernel=None):
+    """Speculative-verify decode against one layer of the int8 paged cache:
+    score T consecutive tokens per row in one call.
+
+    Projections batch over the window.  Position t is bitwise-identical
+    to t successive single-token decodes (``decode_attention_paged``:
+    write tail at ``pt % ps``, read with the ragged mask at ``pt``, then
+    quantize-once flush when ``pt`` fills a page) in its cache writes, and
+    in its output up to the read path's float accumulation order:
+
+    * the jnp read path, and T = 1 on either path, replay that sequence
+      per position inside a ``lax.scan``;
+    * the kernel read path with T > 1 makes one launch per layer
+      (``_paged_multi_kernel``): it flushes every page the window fills
+      up front, and the kernel's page rule (pool pages before the
+      position's own, the bf16 page of its own, none past it) reads for
+      each position exactly what the scan would, by construction.
+
+    ``done`` rows freeze ``pt`` and suppress writes + flushes, exactly
+    like the single-token path (the one-launch path returns zeros for
+    them; every caller discards done rows' outputs).
+
+    Also returns the window's K/V projections in tail dtype — the
+    speculative rollback (core/kvcache.spec_rollback) needs them to rebuild
+    the committed tail when a rejected window crossed a page boundary.
+
+    Returns (out (B, T, D),
+             (k_pages, v_pages, k_scale, v_scale, k_tail, v_tail),
+             (win_k, win_v))  with win_k/win_v (B, T, KV, HD) tail-dtype.
+    """
+    from repro.kernels.paged_attention import use_paged_kernel
+
+    B, T, _ = x.shape
+    pos = view["pos"]
+    KV, HD = view["k_pages"].shape[2:]
+    step = jnp.ones((B,), jnp.int32) if done is None \
+        else jnp.where(done, 0, 1).astype(jnp.int32)
+    offs = jnp.arange(T, dtype=jnp.int32)
+    positions = pos[:, None].astype(jnp.int32) + step[:, None] * offs[None, :]
+    q, k, v = _qkv(params, x, cfg.n_heads, cfg.n_kv, cfg.head_dim,
+                   positions, cfg.rope_theta, cfg.qk_norm, linear, salt)
+    n_rep = q.shape[2] // KV
+    if use_kernel is None:
+        use_kernel = use_paged_kernel(getattr(cfg, "dscim", "off"))
+    win_k = k.astype(view["k_tail"].dtype)                # (B,T,KV,HD)
+    win_v = v.astype(view["v_tail"].dtype)
+    qf = q.astype(jnp.float32).reshape(B, T, KV, n_rep, HD)
+    if use_kernel and T > 1:
+        outs, planes = _paged_multi_kernel(qf, view, win_k, win_v, done,
+                                           par)
+    else:
+        outs, planes = _paged_multi_scan(qf, view, win_k, win_v, done, par,
+                                         use_kernel)
+    out = outs.reshape(B, T, -1).astype(x.dtype)
     out = _mm(out, params["wo"], linear,
               None if salt is None else salt + 7)
     return out, planes, (win_k, win_v)

@@ -412,6 +412,51 @@ def test_paged_attention_kernel_under_mesh():
     assert "OK" in r.stdout, r.stderr[-3000:]
 
 
+def test_paged_chunk_kernel_under_mesh():
+    """The multi-position one-launch path (kernels/paged_attention.py
+    ``paged_attention_decode_chunk_sharded``) under model=4 and
+    data=2,model=4 meshes: outputs and the flushed pool, scales and tails
+    equal the single-device path's (the per-slot page walk is placement-
+    invariant)."""
+    r = _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import parallel_ctx_from_spec
+        from repro.layers.attention import _paged_multi_kernel
+        B, KV, R, HD, ps, MP, T = 4, 2, 2, 16, 4, 6, 8
+        P = B * MP + 2
+        rng = np.random.default_rng(0)
+        i8 = lambda: jnp.asarray(rng.integers(-127, 128, (P, ps, KV, HD)),
+                                 jnp.int8)
+        sc = lambda: jnp.asarray(rng.uniform(0.005, 0.02, (P, KV)),
+                                 jnp.float32)
+        bf = lambda *shape: jnp.asarray(rng.normal(0, 1, shape),
+                                        jnp.bfloat16)
+        view = {"k_pages": i8(), "v_pages": i8(), "k_scale": sc(),
+                "v_scale": sc(), "k_tail": bf(B, ps, KV, HD),
+                "v_tail": bf(B, ps, KV, HD),
+                "page_table": jnp.asarray(
+                    rng.permutation(P)[:B * MP].reshape(B, MP), jnp.int32),
+                "pos": jnp.asarray([1, 6, 0, 9], jnp.int32)}
+        qf = jnp.asarray(rng.normal(0, 1, (B, T, KV, R, HD)), jnp.float32)
+        wk, wv = bf(B, T, KV, HD), bf(B, T, KV, HD)
+        done = jnp.asarray([False, True, False, False])
+
+        def run(par):
+            return jax.jit(lambda *a: _paged_multi_kernel(*a, par))(
+                qf, view, wk, wv, done)
+
+        ref_o, ref_p = run(None)
+        for spec in ("model=4", "data=2,model=4"):
+            o, planes = run(parallel_ctx_from_spec(spec))
+            np.testing.assert_allclose(np.asarray(o), np.asarray(ref_o),
+                                       atol=1e-5)
+            for a, b in zip(planes, ref_p):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        print("OK")
+    """)
+    assert "OK" in r.stdout, r.stderr[-3000:]
+
+
 def test_elastic_mesh_from_env():
     r = _run("""
         import os

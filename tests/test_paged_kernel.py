@@ -1,7 +1,9 @@
 """Fused Pallas paged-attention decode kernel (kernels/paged_attention.py)
 vs the jnp gather reference (layers/attention.py ``_paged_read_jnp``):
 kernel-level parity across page sizes {4, 8, 16}, GQA and MHA geometries,
-ragged positions and arbitrary page-table permutations; model-level logit
+ragged positions and arbitrary page-table permutations; the one-launch
+multi-position path against the per-position scan it replaces (outputs,
+and the pool, scales and tails bitwise); model-level logit
 parity through ``decode_attention_paged`` (pinned via the cache-keyed
 ``paged_attn`` serve option; the dscim-mode default-on selection and its
 ``REPRO_PAGED_ATTN`` env override are covered separately); done-masked
@@ -17,7 +19,8 @@ import pytest
 from repro.configs import get_arch
 from repro.kernels.paged_attention import (paged_attention_decode,
                                            use_paged_kernel)
-from repro.layers.attention import _paged_read_jnp
+from repro.layers.attention import (_paged_multi_kernel, _paged_multi_scan,
+                                    _paged_read_jnp)
 from repro.models import get_model
 
 
@@ -79,6 +82,51 @@ def test_kernel_edge_positions():
         view["v_scale"], kt, vt, view["page_table"], view["pos"],
         interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+# (page size, window T, entry positions, done rows, (KV, n_rep, HD)):
+# entries at and inside page boundaries, windows that cross one or more
+# pages, a window that ends on the table's last page (the padded final
+# chunk of a prompt), and done rows anywhere in the batch
+CHUNK_CASES = [
+    (4, 3, [0, 5, 2], None, (2, 2, 16)),
+    (4, 8, [1, 4, 9], [False, True, False], (2, 2, 16)),
+    (4, 16, [3, 0, 6], [False, False, True], (2, 2, 16)),
+    (8, 3, [7, 8, 6], None, (2, 2, 16)),
+    (8, 8, [0, 13, 4], [True, False, False], (2, 2, 16)),
+    (8, 16, [3, 0, 17], [False, False, True], (2, 2, 16)),
+    (4, 8, [2, 7, 0], None, (16, 1, 8)),       # two kv-head groups
+]
+
+
+@pytest.mark.parametrize("ps,T,pos,done,geom", CHUNK_CASES)
+def test_chunk_matches_per_position_scan(ps, T, pos, done, geom):
+    """The one-launch multi-position path (pre-flush + the chunk entry)
+    against the per-position scan of the decode entry it replaces: live
+    rows' outputs within 1e-5, the pool, scales and tails bitwise equal
+    (pad positions included), done rows' outputs finite."""
+    KV, R, HD = geom
+    B = len(pos)
+    rng = np.random.default_rng(ps * 1000 + T * 10 + KV)
+    MP = -(-(max(pos) + T) // ps)        # the longest window ends on it
+    q, view, kt, vt = _rand_paged(rng, B, KV, R, HD, ps, MP)
+    view = dict(view, pos=jnp.asarray(pos, jnp.int32), k_tail=kt, v_tail=vt)
+    qf = jnp.asarray(rng.normal(0, 1, (B, T, KV, R, HD)), jnp.float32)
+    wk, wv = (jnp.asarray(rng.normal(0, 1, (B, T, KV, HD)), jnp.bfloat16)
+              for _ in range(2))
+    d = None if done is None else jnp.asarray(done)
+    ref, ref_planes = jax.jit(
+        lambda *a: _paged_multi_scan(*a, None, True))(qf, view, wk, wv, d)
+    out, planes = jax.jit(
+        lambda *a: _paged_multi_kernel(*a, None))(qf, view, wk, wv, d)
+    live = ~np.asarray(done if done is not None else [False] * B)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=1e-5)
+    assert np.isfinite(np.asarray(out)).all()
+    for name, a, b in zip(("k_pages", "v_pages", "k_scale", "v_scale",
+                           "k_tail", "v_tail"), planes, ref_planes):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=name)
 
 
 def _serve_logits(cfg, params, prompts, n_tokens, path, **kw):

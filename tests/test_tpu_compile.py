@@ -1,5 +1,6 @@
 """Compile-only guards: both Pallas kernels of the serving path lower for a
-TPU v5e (Mosaic, ``interpret=False``) at Qwen3-0.6B widths.
+TPU v5e (Mosaic, ``interpret=False``) at Qwen3-0.6B widths, and the
+chunked-prefill program calls the paged kernel's chunk entry.
 
 The chip is *described*, not attached (``jax.experimental.topologies``), so
 these run on a CPU-only machine: nothing executes, but the TPU compiler
@@ -98,6 +99,66 @@ def test_paged_attention_compiles_for_v5e(one_chip, ps):
     text = _compiled_text(attend, args)
     assert "tpu_custom_call" in text
     assert paged_attention.KERNEL_NAME in text
+
+
+def test_paged_attention_chunk_compiles_for_v5e(one_chip):
+    """The chunk entry at the serving shape of a 128-token prefill chunk:
+    8 slots, page size 8, a 192-page walk per slot."""
+    T, B, KV, HD, ps, MP = 128, 8, QWEN.n_kv, QWEN.head_dim, 8, 192
+    R = QWEN.n_heads // KV
+    NC = (T + 2 * ps - 2) // ps
+    P = B * MP + 1
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def attend(*args):
+        return paged_attention.paged_attention_decode_chunk(
+            *args, interpret=False)
+
+    args = (spec((B, T, KV, R, HD), jnp.float32),
+            spec((P, ps, KV, HD), jnp.int8), spec((P, ps, KV, HD), jnp.int8),
+            spec((P, KV), jnp.float32), spec((P, KV), jnp.float32),
+            spec((B, NC, ps, KV, HD), jnp.bfloat16),
+            spec((B, NC, ps, KV, HD), jnp.bfloat16),
+            spec((B, MP), jnp.int32), spec((B,), jnp.int32),
+            spec((B,), jnp.bool_))
+    text = _compiled_text(attend, args)
+    assert "tpu_custom_call" in text
+    assert paged_attention.CHUNK_KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("chunk_len", [1, 4])
+def test_extend_engages_chunk_entry(one_chip, monkeypatch, chunk_len):
+    """The chunked-prefill program, lowered for the chip at reduced
+    widths, holds the chunk entry's kernel when a chunk has more than one
+    position, and only the per-position decode entry when it has one."""
+    from repro.launch.steps import init_serve_state, make_extend_fn
+    from repro.models import get_model
+
+    monkeypatch.setattr(paged_attention, "ON_TPU", True)
+    cfg = get_arch("qwen3-0.6b").reduced()
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: get_model(cfg).init_params(cfg, jax.random.PRNGKey(0))))
+    state = place(jax.eval_shape(
+        lambda: init_serve_state(cfg, 2, 24, kv="int8", page_size=4)))
+    extend = make_extend_fn(cfg, None, chunk_len, eos_id=-1,
+                            paged_attn="kernel", jit=False)
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=one_chip)  # noqa: E731
+    text = jax.jit(extend).lower(
+        params, state,
+        jax.ShapeDtypeStruct((1, chunk_len), jnp.int32, sharding=one_chip),
+        scalar(jnp.int32), scalar(jnp.int32), scalar(jnp.bool_),
+        scalar(jnp.int32)).as_text()
+    chunk = f'kernel_name = "{paged_attention.CHUNK_KERNEL_NAME}"'
+    decode = f'kernel_name = "{paged_attention.KERNEL_NAME}"'
+    assert (chunk in text) == (chunk_len > 1)
+    assert (decode in text) == (chunk_len == 1)
 
 
 @pytest.mark.parametrize("arch", ["olmo-1b", "codeqwen1.5-7b",
