@@ -32,13 +32,12 @@ import run                                        # noqa: E402
 
 
 def readings(cell, seed: int, records, w1: float, control: bool) -> dict:
-    import weights
     chk, cfg = cell.check, cell.config
     sample = check.select(records, w1, seed, chk["tokens"], chk["rows"],
                           chk["want_rows"])
     if not sample:
         return {"seed": seed, "requests": 0}
-    params = weights.make(cfg["model"], cfg["init"], seed)
+    params = cell.weights(seed)
     mod = check.reference_module(cfg)
     packed = check.pack(sample, chk["tokens"], chk["rows"])
     alt = None

@@ -1,5 +1,6 @@
 """Finds a cell's files by the names in ``BENCHMARK.json``: the
-configuration (``configs/``), the traffic mix (``mixes/``) and its
+configuration (``configs/``), its parameter layout (``architectures/``,
+by the file's ``architecture`` key), the traffic mix (``mixes/``) and its
 generator (``generators/``), the comparison's limits (``checks/``), the
 per-layer metric readers (``metrics/``), the kernels' work counts
 (``work/``) and the device peaks (``peaks.json``).  Adding any of them is
@@ -50,6 +51,22 @@ class Cell:
     @property
     def name(self) -> str:
         return self.workload["name"]
+
+    def architecture(self):
+        """The configuration's parameter layout,
+        ``architectures/<architecture>.py``: ``make(model, init, seed)``
+        gives the weights in the program's layout; ``position_products``,
+        ``attention_ops`` and ``calls`` give the work the per-layer readers
+        count (``architectures/dense_tied.py`` documents each)."""
+        name = self.config["architecture"]
+        return load_module(os.path.join(self.dir, "architectures",
+                                        f"{name}.py"),
+                           f"bench_architecture_{name}")
+
+    def weights(self, seed: int):
+        """The configuration's weights for ``seed``."""
+        cfg = self.config
+        return self.architecture().make(cfg["model"], cfg["init"], seed)
 
     def generator(self):
         kind = self.mix["generator"]

@@ -1,8 +1,9 @@
 """The whole model step's share of the chip's bf16 peak over the traced
 window: positions computed (chunked-prefill prompt rows and decoded
-tokens) times the model's operations per position — 2 x every matmul
-parameter (attention projections, MLP, head) plus 4 x heads x head_dim x
-context per layer for attention — over window seconds x peak."""
+tokens) times the model's operations per position — 2 x every product one
+position runs (the architecture's ``position_products``) — plus
+attention's operations at each position's context (its
+``attention_ops``), over window seconds x peak."""
 
 
 def read(ctx):
@@ -10,9 +11,9 @@ def read(ctx):
     n = w["prefill_rows"] + w["decoded"]
     if n <= 0 or ctx.window_s <= 0:
         return None
-    d, H, KV, D = m["d_model"], m["n_heads"], m["n_kv"], m["head_dim"]
-    per_layer = d * H * D + 2 * d * KV * D + H * D * d + 3 * d * m["d_ff"]
-    params = m["n_layers"] * per_layer + d * m["vocab_padded"]
-    attn = sum(c * k for c, k in w["contexts"]) * 4 * H * D * m["n_layers"]
+    arch = ctx.architecture
+    params = sum(times * sum(K * N for K, N in prods)
+                 for times, prods in arch.position_products(ctx))
+    attn = sum(k * arch.attention_ops(ctx, c) for c, k in w["contexts"])
     ops = 2 * params * n + attn
     return 100.0 * ops / (ctx.window_s * ctx.peaks["bf16_flops"])

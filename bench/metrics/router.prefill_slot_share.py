@@ -1,0 +1,15 @@
+"""Share of the decode-slot steps in the traced span whose slot held a
+request still in chunked prefill: the window delta of ``Router.stats()``
+``prefill_slot_steps`` over that of ``slot_steps``.  None where the span
+ran no segment or the program does not count prefill slot steps."""
+
+
+def read(ctx):
+    a, b = ctx.stats0, ctx.stats1
+    if "prefill_slot_steps" not in b:
+        return None
+    steps = b["slot_steps"] - a["slot_steps"]
+    if steps <= 0:
+        return None
+    return 100.0 * (b["prefill_slot_steps"] - a["prefill_slot_steps"]) \
+        / steps

@@ -10,7 +10,8 @@ the configuration's model through the program's ``Router`` behind its
 thread (``load.py``):
 
 1. refuse anything but a TPU listed in ``peaks.json`` (exit 3, no result);
-2. make the weights on the device from ``--seed`` (``weights.py``);
+2. make the weights on the device from ``--seed`` (the configuration's
+   module under ``architectures/``);
 3. build the Router from the configuration file;
 4. warm up the cell's programs with one request (one ``extend`` chunk and
    one decode ``segment``) — process start to here is ``setup_s``;
@@ -100,18 +101,16 @@ def require_chip(cell, platform: str | None = "tpu"):
 
 def program_config(cfg: dict):
     """The program's ArchConfig for a configuration file, checked against
-    the file's model sizes."""
+    every key of the file's ``model`` block that names a field or property
+    of ArchConfig."""
     from repro.configs import get_arch
     arch = dataclasses.replace(get_arch(cfg["arch"]),
                                dscim=cfg["dscim"]["spec"],
                                **cfg.get("program", {}))
-    m = cfg["model"]
-    have = {"n_layers": arch.n_layers, "d_model": arch.d_model,
-            "n_heads": arch.n_heads, "n_kv": arch.n_kv,
-            "head_dim": arch.head_dim, "d_ff": arch.d_ff,
-            "vocab_padded": arch.vocab_padded, "qk_norm": arch.qk_norm,
-            "rope_theta": arch.rope_theta}
-    bad = {k: (v, m[k]) for k, v in have.items() if v != m[k]}
+    names = {f.name for f in dataclasses.fields(arch)} | {
+        k for k, v in vars(type(arch)).items() if isinstance(v, property)}
+    bad = {k: (getattr(arch, k), v) for k, v in cfg["model"].items()
+           if k in names and getattr(arch, k) != v}
     if bad:
         raise ValueError(f"program arch {cfg['arch']!r} departs from the "
                          f"configuration file: {bad}")
@@ -131,10 +130,10 @@ def build_router(cfg: dict, arch, params):
                   log=lambda m: None)
 
 
-def counters(router) -> dict:
-    """Segments run and decode-slot steps that served a live request, as
-    the Router counts them (``occupancy`` is live over all slot steps)."""
-    st = router.stats()
+def counters(router, st: dict) -> dict:
+    """Segments run and decode-slot steps that served a live request, from
+    the Router's ``stats()`` ``st`` (``occupancy`` is live over all slot
+    steps)."""
     total = st["segments"] * router.seg_len * router.slots
     return {"segments": st["segments"], "live": st["occupancy"] * total}
 
@@ -181,18 +180,21 @@ async def session(router, cell, plan, args, device) -> dict:
 
     async def tracer():
         """Profile from the first round boundary after ``w0`` for
-        ``trace_s``, reading the counters at the same boundaries."""
+        ``trace_s``, reading the Router's stats at the same boundaries."""
         import jax
         tracemod = cells.load_module(os.path.join(HERE, "trace.py"),
                                      "bench_trace")
         await asyncio.sleep(max(0.0, w0 - time.perf_counter()))
         trace["dir"] = tempfile.mkdtemp(prefix="bench_trace_")
-        c0 = counters(router)
+        s0 = router.stats()
+        c0 = counters(router, s0)
         tracemod.start(trace["dir"])
-        trace["t0"], trace["c0"] = time.perf_counter(), c0
+        trace["t0"], trace["c0"], trace["stats0"] = \
+            time.perf_counter(), c0, s0
         await asyncio.sleep(max(0.0, min(float(mix["trace_s"]),
                                          w1 - time.perf_counter())))
-        trace["t1"], trace["c1"] = time.perf_counter(), counters(router)
+        trace["t1"], s1 = time.perf_counter(), router.stats()
+        trace["c1"], trace["stats1"] = counters(router, s1), s1
         # collecting the trace holds the serving loop for tens of seconds;
         # from a thread of its own, racing that loop for the GIL, it took
         # minutes.  Nothing after the span is reported in a traced run.
@@ -263,7 +265,8 @@ def per_layer(cell, out, peaks) -> tuple:
     ctx = types.SimpleNamespace(
         summary=summary, window_s=window_s, work=work, peaks=peaks,
         model=cell.config["model"], serve=serve,
-        kernel_work=cell.kernel_work)
+        kernel_work=cell.kernel_work, architecture=cell.architecture(),
+        stats0=tr["stats0"], stats1=tr["stats1"])
     metrics = {}
     for m in cell.metrics("per_layer"):
         v = cell.metric_reader(m["name"]).read(ctx)
@@ -278,14 +281,13 @@ def per_layer(cell, out, peaks) -> tuple:
 
 def compare(cell, seed: int, records, w1: float) -> dict:
     """The reference over a sample of finished requests (check.py)."""
-    import weights
     chk, cfg = cell.check, cell.config
     gap = {"value": None, "limit": chk["mean_logit_gap"]}
     sample = check.select(records, w1, seed, chk["tokens"], chk["rows"],
                           chk["want_rows"])
     if not sample:
         return {"mean_logit_gap": gap, "requests": 0, "positions": 0}
-    params = weights.make(cfg["model"], cfg["init"], seed)
+    params = cell.weights(seed)
     packed = check.pack(sample, chk["tokens"], chk["rows"])
     t = time.perf_counter()
     g, _ = check.gaps(check.reference_module(cfg).build(cfg), params, packed)
@@ -299,10 +301,9 @@ def compare(cell, seed: int, records, w1: float) -> dict:
 def serve_window(cell, args, device) -> dict:
     """Set up, serve the cell's traffic and measure one window; the
     program's state is freed when this returns."""
-    import weights
     cfg = cell.config
     arch = program_config(cfg)
-    params = weights.make(cfg["model"], cfg["init"], args.seed)
+    params = cell.weights(args.seed)
     router = build_router(cfg, arch, params)
     del params
     plan = cell.generator().make(cell.mix, seed=args.seed,

@@ -32,6 +32,7 @@ def tiny_config() -> dict:
     return {
         "name": "tiny", "arch": "qwen3-0.6b", "source": "test",
         "reduced": [], "reference": "dense_decoder",
+        "architecture": "dense_tied",
         "program": {k: m[k] for k in ("n_layers", "d_model", "n_heads",
                                       "n_kv", "head_dim", "d_ff", "vocab")}
         | {"vocab_pad_mult": 256, "compute_dtype": "bfloat16",

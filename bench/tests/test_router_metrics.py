@@ -1,5 +1,6 @@
 """The Router's per-layer metrics: ``router.host_ms`` on hand-made host
-spans, and in a traced run of the tiny cell."""
+spans, and the trace's and the counters' metrics in a traced run of the
+tiny cell."""
 from __future__ import annotations
 
 import json
@@ -62,3 +63,9 @@ def test_traced_run_reports_router_metrics(tiny_cell, capsys):
     assert res["correct"] is True
     assert res["metrics"]["router.host_ms"]["value"] > 0
     assert res["metrics"]["router.host_ms"]["unit"] == "ms"
+    # the counters of Router.stats() at the span's ends reach their readers
+    shares = [res["metrics"][f"router.{k}_slot_share"]["value"]
+              for k in ("empty", "prefill")]
+    occ = res["metrics"]["router.occupancy"]["value"]
+    assert all(0 <= v <= 100 for v in shares) and sum(shares) + occ <= 100
+    assert res["metrics"]["router.queue_wait_ms"]["value"] >= 0
